@@ -316,7 +316,9 @@ def run_placement(dataset="tiny", backend="oracle", queries=32, topk=10,
     rng = np.random.default_rng(seed + 2)
     engine.delete(np.sort(rng.choice(n, n // 16, replace=False)).tolist())
 
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((len(jax.devices()),), ("data",))
     d = len(jax.devices())
     q = jnp.asarray(idx[rng.choice(n, queries, replace=False)])
 
@@ -1102,7 +1104,9 @@ def _smoke_mutate_cycle():
         # multidevice test suite); ids exact up to provable score ties
         from repro.engine.testing import assert_topk_equivalent, topk_truth
 
-        mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((len(jax.devices()),), ("data",))
         sc_p, id_p = eng.query_sharded(mesh, "data", q, 5)
         assert_topk_equivalent((sc_p, id_p), (sc_m, id_m),
                                truth=topk_truth(eng, q))
@@ -1127,6 +1131,9 @@ def main(argv=None):
     ap.add_argument("--out", default="BENCH_engine.json")
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         return smoke()
 

@@ -152,7 +152,7 @@ def _scan_jaxpr(jaxpr, hits: List[str]) -> None:
 
 
 def _subjaxprs(val):
-    import jax.core as jcore
+    import jax.extend.core as jcore
     if isinstance(val, jcore.ClosedJaxpr):
         yield val.jaxpr
     elif isinstance(val, jcore.Jaxpr):

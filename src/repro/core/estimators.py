@@ -25,6 +25,7 @@ Hamming convention (see DESIGN.md §1): symmetric difference
 
 from __future__ import annotations
 
+import math
 from typing import Dict
 
 import jax.numpy as jnp
@@ -42,15 +43,27 @@ __all__ = [
 def cardinality_from_fill(count: jnp.ndarray, n_bins: int) -> jnp.ndarray:
     """Estimate |a| from the sketch fill count |a_s| (Alg 1 line 3).
 
-    ``card = ln(1 - c/N) / ln(1 - 1/N)``, computed as
-    ``(ln(N - c) - ln N) / log1p(-1/N)`` so precision survives c -> N in fp32.
-    A full sketch (c == N) is clipped to c = N - 0.5 (estimate saturates,
-    mirroring the paper's requirement that N be sized to keep fill < 1/2).
+    ``card = ln(1 - c/N) / ln(1 - 1/N)``. The log is ``log1p(-c/N)`` below
+    half fill, where sketches are sized to live, and ``ln(N - c) - ln N``
+    above it: each form keeps fp32 precision on its side (the difference of
+    logs alone loses ~1e-4 of the result at c << N). A full sketch (c == N)
+    is clipped to c = N - 0.5 (estimate saturates, mirroring the paper's
+    requirement that N be sized to keep fill < 1/2).
+
+    Plain element-wise jnp, so the Pallas kernels call this same function
+    in-kernel: kernel and oracle then round alike on any device. (On a TPU
+    v5e the f32 ``log``/``log1p`` are coarser than on the CPU, ~1e-4
+    absolute, in XLA and in Mosaic alike.)
     """
     n = float(n_bins)
     c = jnp.clip(count.astype(jnp.float32), 0.0, n - 0.5)
-    remaining = jnp.maximum(n - c, 0.5)
-    return (jnp.log(remaining) - jnp.log(n)) / jnp.log1p(-1.0 / n)
+    low = jnp.log1p(-c / jnp.float32(n))
+    high = jnp.log(n - c) - jnp.float32(math.log(n))
+    # ln(1 - 1/N), -inf at N = 1 (every estimate 0). A division, not a
+    # multiply by the reciprocal: XLA may fuse a multiply into the caller's
+    # sums as an FMA, and then results vary with the shape it compiled for
+    ln_n = math.log1p(-1.0 / n) if n > 1 else -math.inf
+    return jnp.where(c < n / 2, low, high) / jnp.float32(ln_n)
 
 
 def estimates_from_counts(

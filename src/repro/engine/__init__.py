@@ -41,6 +41,7 @@ from .supervision import (
     JobSupervisor,
     SupervisedJob,
     SupervisionPolicy,
+    health_faults,
 )
 
 __all__ = [
@@ -67,6 +68,7 @@ __all__ = [
     "available_backends",
     "from_legacy_scorer",
     "get_backend",
+    "health_faults",
     "merge_segment_topk",
     "register_backend",
     "shard_topk",

@@ -230,6 +230,15 @@ class PallasBackend:
         self.name = name
         self.interpret = interpret
 
+    @property
+    def interpreted(self) -> bool:
+        """Whether the kernels run in Pallas interpret mode: the forced
+        flag, else resolved from the platform (compiled only on a TPU)."""
+        from ..kernels import ops
+
+        return (ops._interpret_default() if self.interpret is None
+                else bool(self.interpret))
+
     def sketch(self, cfg, mapping, idx):
         from ..kernels import ops
 
@@ -255,10 +264,8 @@ class PallasBackend:
         from ..kernels import ops
 
         c = corpus.shape[0]
-        interp = (ops._interpret_default() if self.interpret is None
-                  else self.interpret)
         if 0 < c and (c < self.topk_crossover
-                      or (interp and self.topk_crossover > 0)):
+                      or (self.interpreted and self.topk_crossover > 0)):
             # materialize path: one (Q, C) score tile + lax.top_k — faster
             # than the streaming sort network on small corpora and at every
             # size under interpret-mode emulation; identical results (same

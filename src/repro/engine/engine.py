@@ -50,7 +50,6 @@ from .. import obs
 from ..core import binsketch
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
-from ..parallel.sharding import shard_map
 from . import backends as backends_mod
 from .backends import Backend
 from .banding import BandPolicy
@@ -913,10 +912,29 @@ class SketchEngine:
                 if use_placement:
                     pf = self._resolve_prefilter(prefilter)  # misuse raises pre-try
                     try:
-                        return self._query_placed(
-                            mesh, axis, query_idx, k, now=now, prefilter=pf,
-                            tr=tr,
-                        )
+                        # planner chunks as in query(): the banded candidate
+                        # set is the union over one chunk's queries, so the
+                        # chunking is part of the result
+                        stats = self._fresh_prefilter_stats() if pf else None
+                        out_s, out_i = [], []
+                        for chunk in self.planner.plan(n_q):
+                            t0 = time.perf_counter() if tr is not None else 0.0
+                            qs = self._padded_query_sketches(
+                                query_idx[chunk.start : chunk.start + chunk.rows],
+                                chunk.padded,
+                            )
+                            if tr is not None:
+                                tr.add_stage("rebucket", time.perf_counter() - t0)
+                            sc, ix = self._query_placed(
+                                mesh, axis, qs, chunk.rows, k, now=now,
+                                stats=stats, tr=tr,
+                            )
+                            out_s.append(sc[: chunk.rows])
+                            out_i.append(ix[: chunk.rows])
+                        if pf:
+                            self.last_prefilter_stats = stats
+                        return (jnp.concatenate(out_s, axis=0),
+                                jnp.concatenate(out_i, axis=0))
                     except Exception as e:
                         # placement (build or mask refresh) is an accelerator:
                         # on failure, drop the cached placement and serve this
@@ -957,6 +975,13 @@ class SketchEngine:
             return got
         finally:
             obs_trace.finish(tr)
+
+    def place(self, mesh: Mesh, axis: str) -> SegmentPlacement:
+        """Place the sealed segments on ``mesh`` now (``query_sharded``
+        otherwise places lazily at its first call) and return the
+        placement. A background :meth:`compact` issued while this placement
+        is live merges device-locally — one output segment per device."""
+        return self._ensure_placement(mesh, axis)
 
     def _ensure_placement(self, mesh: Mesh, axis: str) -> SegmentPlacement:
         """Current placement, rebuilt only when the sealed-segment *set*
@@ -1075,7 +1100,7 @@ class SketchEngine:
             return (jax.lax.all_gather(sc, axis, axis=1, tiled=True),
                     jax.lax.all_gather(gids, axis, axis=1, tiled=True))
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(), P(axis, None), P(axis), P(axis), P(axis), P(axis)),
@@ -1095,14 +1120,17 @@ class SketchEngine:
         self,
         mesh: Mesh,
         axis: str,
-        query_idx: jax.Array,
+        qs: jax.Array,
+        rows: int,
         k: int,
         *,
         now: Optional[float] = None,
-        prefilter: bool = False,
+        stats: Optional[dict] = None,
         tr=None,
     ) -> Tuple[jax.Array, jax.Array]:
-        """Segment-placed sharded query body (see :meth:`query_sharded`).
+        """Segment-placed sharded query body for one planner chunk of query
+        sketches ``qs``, ``rows`` of them real (see :meth:`query_sharded`);
+        ``stats`` (prefilter counters) turns the prefilter on.
 
         One shard_map pass per resident sketch **width** (base + every
         distilled tier): each device streams the fused top-k over its
@@ -1129,10 +1157,7 @@ class SketchEngine:
         """
         store: SegmentedStore = self.store
         placement = self._ensure_placement(mesh, axis)
-        t0 = time.perf_counter() if tr is not None else 0.0
-        qs = self._sketch_queries(query_idx)
-        if tr is not None:
-            tr.add_stage("rebucket", time.perf_counter() - t0)
+        prefilter = stats is not None
         hv = store.head_view(now)
         if not placement.slabs:
             # no sealed rows anywhere: the head is the whole corpus
@@ -1144,7 +1169,6 @@ class SketchEngine:
         measure, backend = self.measure, self.backend
         cache: dict = {}
         qkeys_cache: dict = {}
-        stats = self._fresh_prefilter_stats() if prefilter else None
         parts_s, parts_i = [], []
         for slab in placement.slabs:
             q_w = self._rebucket_queries(qs, slab.n_bins, cache)
@@ -1154,7 +1178,7 @@ class SketchEngine:
             if prefilter:
                 t0 = time.perf_counter() if tr is not None else 0.0
                 qkeys = self._query_band_keys(
-                    qs, slab.n_bins, qs.shape[0], cache, qkeys_cache
+                    qs, slab.n_bins, rows, cache, qkeys_cache
                 )
                 slots = self._slab_candidates(slab, qkeys, now, stats, tr=tr)
                 if tr is not None:
@@ -1182,7 +1206,7 @@ class SketchEngine:
                 return (jax.lax.all_gather(sc, axis, axis=1, tiled=True),
                         jax.lax.all_gather(gids, axis, axis=1, tiled=True))
 
-            fn = shard_map(
+            fn = jax.shard_map(
                 local,
                 mesh=mesh,
                 in_specs=(P(), P(axis, None), P(axis), P(axis), P(axis)),
@@ -1201,8 +1225,6 @@ class SketchEngine:
                                            tr=tr)
             parts_s.append(h_sc)
             parts_i.append(h_ids)
-        if prefilter:
-            self.last_prefilter_stats = stats
         if not parts_s:  # prefilter skipped every slab and the head is empty
             return (jnp.full((qs.shape[0], k), -jnp.inf, jnp.float32),
                     jnp.full((qs.shape[0], k), -1, jnp.int32))
@@ -1243,7 +1265,7 @@ class SketchEngine:
                 cand_ids=cand_ids, cand_valid=cand_valid,
             )
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(), P(axis, None), P(axis), P(axis), P(axis)),

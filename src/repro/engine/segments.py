@@ -112,26 +112,40 @@ def _grow_host(arr: np.ndarray, new_capacity: int) -> np.ndarray:
     return out
 
 
-def _fold_packed_host(sk: np.ndarray, n_bins: int, n_bins_new: int):
+def _fold_packed_host(sk: np.ndarray, n_bins: int, n_bins_new: int,
+                      chunk: int = 32768):
     """Numpy twin of ``core.packed.fold_packed`` + fill re-gather, for the
     distillation worker thread (pure host math, no device dispatch that
     could contend with serving). Returns ``(folded (n, W') uint32,
-    fills (n,) int32)``. Little-endian byte order assumed (bin ``j`` lives
-    at byte ``j // 8`` bit ``j % 8`` of the uint32-word row — true on
-    every platform this repo targets)."""
-    raw = np.ascontiguousarray(sk).view(np.uint8)
-    bits = np.unpackbits(raw, axis=1, bitorder="little")[:, :n_bins]
+    fills (n,) int32)``.
+
+    Word-level funnel shift, as in the ``kernels.rebucket`` kernel: source
+    chunk ``q`` (bits ``[q·N', (q+1)·N')``) starts at bit ``s`` of word
+    ``lo`` and ORs in as ``(src[lo + w] >> s) | (src[lo + w + 1] << (32 -
+    s))``; bits past N' are masked once at the end. Rows go in blocks of
+    ``chunk`` so the temporaries stay bounded on a full-size slab."""
+    sk = np.asarray(sk, np.uint32)
+    n, w = sk.shape
+    w_new = pk.num_words(n_bins_new)
     n_chunks = -(-n_bins // n_bins_new)
-    pad = n_chunks * n_bins_new - n_bins
-    if pad:
-        bits = np.pad(bits, ((0, 0), (0, pad)))
-    folded = bits.reshape(-1, n_chunks, n_bins_new).max(axis=1)
-    out = np.packbits(folded, axis=1, bitorder="little")
-    w_bytes = pk.num_words(n_bins_new) * 4
-    if out.shape[1] < w_bytes:
-        out = np.pad(out, ((0, 0), (0, w_bytes - out.shape[1])))
-    return (np.ascontiguousarray(out).view(np.uint32),
-            folded.sum(axis=1, dtype=np.int32))
+    w_src = max(w, ((n_chunks - 1) * n_bins_new) // 32 + w_new + 1)
+    tail = np.full(w_new, 0xFFFFFFFF, np.uint32)
+    if n_bins_new % 32:
+        tail[-1] = (1 << (n_bins_new % 32)) - 1
+    folded = np.empty((n, w_new), np.uint32)
+    for r0 in range(0, n, chunk):
+        src = np.zeros((min(chunk, n - r0), w_src), np.uint32)
+        src[:, :w] = sk[r0 : r0 + chunk]
+        if n_bins % 32:  # source pad bits past N never fold in
+            src[:, w - 1] &= np.uint32((1 << (n_bins % 32)) - 1)
+        acc = np.zeros((len(src), w_new), np.uint32)
+        for q in range(n_chunks):
+            lo, sh = divmod(q * n_bins_new, 32)
+            acc |= src[:, lo : lo + w_new] >> np.uint32(sh)
+            if sh:
+                acc |= src[:, lo + 1 : lo + 1 + w_new] << np.uint32(32 - sh)
+        folded[r0 : r0 + chunk] = acc & tail
+    return folded, np.bitwise_count(folded).sum(axis=1, dtype=np.int32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -540,6 +554,11 @@ class SegmentedStore:
         supervisor: Optional[JobSupervisor] = None,
         clock: Optional[Callable[[], float]] = None,
     ) -> "SegmentedStore":
+        # the head never needs more rows than it seals at: its u16 counter
+        # matrix costs 2·N bytes per row (20.9 GB for 300k rows at
+        # N = 34,851), so an auto-sealing head is sized to ``seal_rows``
+        if seal_rows is not None:
+            capacity = min(int(capacity), int(seal_rows))
         return cls(
             cfg, mapping, [], _Head.create(cfg.n_bins, cfg.n_words, capacity),
             seal_rows=seal_rows, ttl=ttl, band_policy=band_policy,
@@ -1060,7 +1079,10 @@ class SegmentedStore:
                 self._loc[int(gid)] = (seg_i, row)
             obs_metrics.inc("lifecycle.seal.runs")
             obs_metrics.inc("lifecycle.seal.rows", seg.n_rows)
-        self.head = _Head.create(self.cfg.n_bins, self.cfg.n_words, h.capacity)
+        cap = h.capacity
+        if self.seal_rows is not None:  # an overshooting batch grew it
+            cap = min(cap, int(self.seal_rows))
+        self.head = _Head.create(self.cfg.n_bins, self.cfg.n_words, cap)
         self._layout_epoch += 1
         return seg
 

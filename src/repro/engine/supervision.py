@@ -59,6 +59,7 @@ __all__ = [
     "JobSupervisor",
     "SupervisedJob",
     "SupervisionPolicy",
+    "health_faults",
 ]
 
 log = logging.getLogger("repro.supervision")
@@ -486,3 +487,27 @@ class JobSupervisor:
                 "last_error": dict(self._last_error) if self._last_error else None,
                 "latency_s": lat,
             }
+
+
+#: degraded components that are a designed fallback, not a fault: the
+#: prefilter's selectivity escape hatch (a candidate union too large to pay
+#: for its gather) serves the exhaustive scan with no error behind it
+_BENIGN_DEGRADED = frozenset({"prefilter_hatch"})
+
+
+def health_faults(health: dict) -> List[str]:
+    """What in a :meth:`JobSupervisor.health` snapshot is a fault: degraded
+    components that an exception put there, terminally failed or
+    abandoned jobs, and quarantined ops — one line each (empty when clean).
+
+    The chaos-tolerant fallbacks keep serving through all of these; with
+    no fault plan armed, any of them means a real failure (a kernel the
+    device refused, a worker that crashed) that the fallback hid."""
+    out = [f"degraded {d['component']}: {d['reason']}"
+           for d in health["degraded"] if d["component"] not in _BENIGN_DEGRADED]
+    for op, c in sorted(health["jobs"].items()):
+        for field in ("failed", "abandoned"):
+            if c.get(field):
+                out.append(f"job {op}: {c[field]} {field}")
+    out.extend(f"quarantined {q['op']}" for q in health["quarantined"])
+    return out

@@ -24,7 +24,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-__all__ = ["assert_topk_equivalent", "topk_truth"]
+__all__ = ["assert_topk_equivalent", "score_ids", "topk_truth"]
 
 
 def topk_truth(engine, query_idx, id_map=None) -> List[Dict[int, float]]:
@@ -43,6 +43,36 @@ def topk_truth(engine, query_idx, id_map=None) -> List[Dict[int, float]]:
         {int(g): float(s[r, j]) for j, g in enumerate(ids)}
         for r in range(s.shape[0])
     ]
+
+
+def score_ids(engine, query_idx, ids) -> List[Dict[int, float]]:
+    """Per-query ``{global doc id: score}`` for just the docs in ``ids``
+    ((Q, m), -1 skipped), scored by ``engine``'s backend at each doc's own
+    sketch width — the :func:`topk_truth` of a corpus too large for
+    ``score_all``'s (Q, C) matrix. Pass the union of two results' ids for
+    :func:`assert_topk_equivalent`'s tie check."""
+    import jax.numpy as jnp
+
+    from .segments import _HEAD, SegmentedStore
+
+    store, be = engine.store, engine.backend
+    base = engine.cfg.n_bins
+    qs = be.sketch(engine.cfg, store.mapping, jnp.asarray(query_idx))
+    out: List[Dict[int, float]] = []
+    for r, row in enumerate(np.asarray(ids)):
+        scores: Dict[int, float] = {}
+        for g in sorted({int(x) for x in row if x >= 0}):
+            if not isinstance(store, SegmentedStore):
+                doc, nb = store.sketches[g : g + 1], base
+            else:
+                seg_i, pos = store._locate(g)
+                seg = store.head if seg_i == _HEAD else store.sealed[seg_i]
+                doc = (seg.packed if seg_i == _HEAD else seg.sketches)[pos : pos + 1]
+                nb = getattr(seg, "n_bins", None) or base
+            q = qs[r : r + 1] if nb == base else be.rebucket(qs[r : r + 1], base, nb)
+            scores[g] = float(be.score(q, doc, nb, engine.measure)[0, 0])
+        out.append(scores)
+    return out
 
 
 def assert_topk_equivalent(

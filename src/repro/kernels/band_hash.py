@@ -12,19 +12,19 @@ identical (uint32 wraparound) to the jnp oracle ``core.packed.band_hash``
 and its numpy host twin — the kernel exists so index (re)builds at seal /
 compact / distill time ride the same accelerator as the slab they hash.
 
-Grid: (rows / TB,). Each program loads its (TB, W_pad) word slab (the
-wrapper pads the word axis to ``nb_eff * wpb`` with zeros — zero words
-still mix the seed, and every row pads identically so collisions are
-unaffected), views it as (TB, nb_eff, wpb), and folds the ``wpb`` word
-lanes into the (TB, nb_eff) key block with a static loop.
+Layout: the wrapper hands the words in as ``(wpb, nb_eff, B)`` — word
+position within the band leading, bands on sublanes, rows on lanes — so
+step ``j`` of the chain is one leading-index load ``src[j]`` of a
+(nb_eff, TB) tile that advances every band of TB rows at once.
 
-VMEM per program (TB=8, W<=2048 words): 8·2048·4 B = 64 KiB in, the
-(TB, nb_eff) out block is tiny — trivially resident.
+Grid: (rows / TB,), a trailing partial block allowed. Each program walks
+the ``wpb`` steps in a loop and writes its (nb_eff, TB) key block.
+
+VMEM per program (TB=128, W<=2048 words): 128·2048·4 B = 1 MiB in
+(double-buffered 2 MiB); the key block is tiny.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -35,41 +35,32 @@ from ..core.packed import _BAND_PRIME, _BAND_SEED
 __all__ = ["band_hash_kernel"]
 
 
-def _kernel(src_ref, out_ref, *, nb_eff: int, wpb: int):
-    src = src_ref[...]  # (TB, nb_eff * wpb) uint32
-    tb = src.shape[0]
-    grp = src.reshape(tb, nb_eff, wpb)
-    band = jax.lax.broadcasted_iota(jnp.uint32, (tb, nb_eff), 1)
-    h = jnp.uint32(_BAND_SEED) * (band + jnp.uint32(1))
-    for t in range(wpb):
-        h = (h ^ grp[:, :, t]) * jnp.uint32(_BAND_PRIME)
-        h = h ^ (h >> jnp.uint32(15))
-    out_ref[...] = h
+def _kernel(src_ref, out_ref):
+    band = jax.lax.broadcasted_iota(jnp.uint32, out_ref.shape, 0)
+    h0 = jnp.uint32(_BAND_SEED) * (band + jnp.uint32(1))
+
+    def step(t, h):
+        h = (h ^ src_ref[t]) * jnp.uint32(_BAND_PRIME)
+        return h ^ (h >> jnp.uint32(15))
+
+    out_ref[...] = jax.lax.fori_loop(0, src_ref.shape[0], step, h0)
 
 
 def band_hash_kernel(
     src: jax.Array,
-    nb_eff: int,
-    wpb: int,
     *,
-    block_rows: int = 8,
+    block_rows: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    """``src: (B, nb_eff*wpb)`` packed rows -> ``(B, nb_eff)`` uint32 band keys.
-
-    B must be a multiple of ``block_rows`` and the word axis exactly
-    ``nb_eff * wpb``; ``ops.band_hash`` handles row/word padding, the
-    band-count clamp, and the crops.
-    """
-    bsz, w_pad = src.shape
-    assert bsz % block_rows == 0, bsz
-    assert w_pad == nb_eff * wpb, (w_pad, nb_eff, wpb)
-    grid = (bsz // block_rows,)
+    """``src: (wpb, nb_eff, B)`` band-grouped words -> ``(nb_eff, B)``
+    uint32 band keys; ``ops.band_hash`` does the grouping, the band-count
+    clamp and the transposes."""
+    wpb, nb_eff, bsz = src.shape
     return pl.pallas_call(
-        functools.partial(_kernel, nb_eff=nb_eff, wpb=wpb),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, w_pad), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_rows, nb_eff), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bsz, nb_eff), jnp.uint32),
+        _kernel,
+        grid=(pl.cdiv(bsz, block_rows),),
+        in_specs=[pl.BlockSpec((wpb, nb_eff, block_rows), lambda i: (0, 0, i))],
+        out_specs=pl.BlockSpec((nb_eff, block_rows), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((nb_eff, bsz), jnp.uint32),
         interpret=interpret,
     )(src)

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: fused hash + compare-reduce sketch construction.
+"""Pallas TPU kernel: fused hash + compare-OR sketch construction.
 
 ``sketch_build`` takes pre-mapped bin ids — fine when the pi table exists.
 At tera-scale d (the paper's motivating regime) there is no table: the map
@@ -7,10 +7,9 @@ trip of the (B, P) int32 bins; this kernel computes
 
     bin = ((a * idx + b) mod 2^32) mod N
 
-inside the kernel body (VPU integer ops) and feeds the same broadcast-
-compare + OR-reduce + pack pipeline, so raw indices stream from HBM
-exactly once. Coefficients arrive as a (2,) uint32 operand replicated to
-every program.
+inside the kernel body (VPU integer ops) and feeds the same compare-OR
+pack (``sketch_build.or_pack_tile``), so raw indices stream from HBM
+exactly once. The coefficients arrive as a (2,) uint32 operand in SMEM.
 """
 
 from __future__ import annotations
@@ -20,60 +19,50 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .sketch_build import or_pack_tile
 
 __all__ = ["hash_build_kernel"]
 
 
 def _kernel(coeffs_ref, idx_ref, out_ref, *, tile_words: int, n_bins: int):
-    j = pl.program_id(1)
-    idx = idx_ref[...]  # (TB, P) int32 raw feature indices, pad = -1
     a = coeffs_ref[0]
     b = coeffs_ref[1]
-    valid = idx >= 0
-    h = a * idx.astype(jnp.uint32) + b  # wraps mod 2^32
-    bins = (h % jnp.uint32(n_bins)).astype(jnp.int32)
-    bins = jnp.where(valid, bins, -1)
 
-    n_bits = tile_words * 32
-    base = j * n_bits
-    targets = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_bits), 2)
-    hits = jnp.any(bins[:, :, None] == targets, axis=1)  # (TB, n_bits)
-    words = hits.reshape(idx.shape[0], tile_words, 32).astype(jnp.uint32)
-    weights = (jnp.uint32(1) << jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)).astype(
-        jnp.uint32
-    )
-    out_ref[...] = jnp.sum(words * weights, axis=-1).astype(jnp.uint32)
+    def to_bins(idx):  # (8, TB) int32 raw feature indices, pad = -1
+        h = a * idx.astype(jnp.uint32) + b  # wraps mod 2^32
+        bins = (h % jnp.uint32(n_bins)).astype(jnp.int32)
+        return jnp.where(idx >= 0, bins, -1)
+
+    out_ref[...] = or_pack_tile(idx_ref, tile_words, to_bins)
 
 
 def hash_build_kernel(
-    idx: jax.Array,
+    idx_t: jax.Array,
     coeffs: jax.Array,
     n_bins: int,
     *,
-    n_words: int | None = None,  # padded output width (>= ceil(n_bins/32))
-    block_rows: int = 8,
-    tile_words: int = 16,
+    block_rows: int = 128,
+    tile_words: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    """``idx: (B, P)`` raw indices (pad=-1), ``coeffs: (2,)`` uint32
-    multiply-shift pair -> packed ``(B, n_words)`` uint32 sketches; the
-    modulo uses the true ``n_bins`` (bits beyond it are always zero).
-
-    Dims must divide the block shapes (``ops.hash_build_sketch`` pads/crops).
-    """
-    bsz, _ = idx.shape
-    if n_words is None:
-        n_words = (n_bins + 31) // 32
-    assert bsz % block_rows == 0 and n_words % tile_words == 0, (bsz, n_words)
-    grid = (bsz // block_rows, n_words // tile_words)
+    """``idx_t: (P, B)`` transposed raw indices (pad -1, P a multiple of
+    8), ``coeffs: (2,)`` uint32 multiply-shift pair -> packed
+    ``(B, ceil(n_bins/32))`` int32 sketches (``ops.hash_build_sketch``
+    transposes in and bitcasts out)."""
+    p, bsz = idx_t.shape
+    assert p % 8 == 0, p
+    n_words = (n_bins + 31) // 32
+    grid = (pl.cdiv(bsz, block_rows), pl.cdiv(n_words, tile_words))
     return pl.pallas_call(
         functools.partial(_kernel, tile_words=tile_words, n_bins=n_bins),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((2,), lambda i, j: (0,)),
-            pl.BlockSpec((block_rows, idx.shape[1]), lambda i, j: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((p, block_rows), lambda i, j: (0, i)),
         ],
         out_specs=pl.BlockSpec((block_rows, tile_words), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((bsz, n_words), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((bsz, n_words), jnp.int32),
         interpret=interpret,
-    )(coeffs, idx)
+    )(coeffs, idx_t)

@@ -1,9 +1,16 @@
-"""Jit'd public wrappers for the Pallas kernels: padding, dtype checks,
+"""Jit'd public wrappers for the Pallas kernels: layout, dtype checks,
 interpret-mode fallback off-TPU, and estimator plumbing.
 
 These are the entry points the rest of the framework uses — primarily the
 ``pallas*`` backends in ``repro.engine.backends`` (which stream the
 ``SketchStore`` fill cache in via ``a_fills``/``b_fills``) plus benchmarks.
+
+Block sizes: on a TPU every block dim must be the whole axis or a multiple
+of the (8, 128) tile (sublane, lane). The defaults are 128 on every tiled
+axis and each wrapper clamps a block to its axis, so a small axis becomes
+one whole-axis block. Blocks need not divide their axes — the kernels run
+a trailing partial block and mask what it reads past the end — so no
+corpus-sized operand is ever padded or copied here.
 """
 
 from __future__ import annotations
@@ -34,12 +41,22 @@ def _interpret_default() -> bool:
 
 def _pad_to(x: jax.Array, axis: int, multiple: int, fill) -> jax.Array:
     size = x.shape[axis]
-    target = -(-size // multiple) * multiple
+    target = _round_up(size, multiple)
     if target == size:
         return x
     pads = [(0, 0)] * x.ndim
     pads[axis] = (0, target - size)
     return jnp.pad(x, pads, constant_values=fill)
+
+
+def _round_up(n: int, multiple: int) -> int:
+    return -(-n // multiple) * multiple
+
+
+def _slots_t(idx: jax.Array) -> jax.Array:
+    """(B, P) padded index/bin slots -> (P8, B) int32, P padded with -1 to
+    a multiple of 8: the rows-on-lanes layout of the compare-OR builds."""
+    return _pad_to(idx.astype(jnp.int32).T, 0, 8, -1)
 
 
 @functools.partial(
@@ -49,31 +66,28 @@ def build_sketch(
     bins: jax.Array,
     n_bins: int,
     *,
-    block_rows: int = 8,
-    tile_words: int = 16,
+    block_rows: int = 128,
+    tile_words: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Pre-mapped padded bin ids (B, P) -> packed sketches (B, ceil(N/32)).
 
-    Pads rows to ``block_rows`` (pad rows are all -1 -> zero sketches) and
-    the word axis to ``tile_words``; crops both on return. Bin ids >= n_bins
-    are treated as padding by construction (they never match a target).
-    """
+    Pad slots are -1. Bin ids >= n_bins must not occur (``map_indices``
+    never produces them)."""
     if interpret is None:
         interpret = _interpret_default()
     bsz = bins.shape[0]
     n_words = pk.num_words(n_bins)
-    tile_words = min(tile_words, n_words) if n_words % min(tile_words, n_words) == 0 else 1
-    padded_rows = _pad_to(bins.astype(jnp.int32), 0, block_rows, -1)
-    n_words_padded = -(-n_words // tile_words) * tile_words
+    if bsz == 0:
+        return jnp.zeros((0, n_words), jnp.uint32)
     out = sketch_build.build_sketch_kernel(
-        padded_rows,
-        n_words_padded * 32,
-        block_rows=block_rows,
-        tile_words=tile_words,
+        _slots_t(bins),
+        n_words,
+        block_rows=min(block_rows, bsz),
+        tile_words=min(tile_words, n_words),
         interpret=interpret,
     )
-    return out[:bsz, :n_words]
+    return jax.lax.bitcast_convert_type(out, jnp.uint32)
 
 
 @functools.partial(
@@ -119,8 +133,8 @@ def hash_build_sketch(
     coeffs: jax.Array,
     n_bins: int,
     *,
-    block_rows: int = 8,
-    tile_words: int = 16,
+    block_rows: int = 128,
+    tile_words: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused hash+build: raw indices (B, P) + (2,) uint32 multiply-shift
@@ -130,19 +144,17 @@ def hash_build_sketch(
         interpret = _interpret_default()
     bsz = idx.shape[0]
     n_words = pk.num_words(n_bins)
-    tile_words = min(tile_words, n_words) if n_words % min(tile_words, n_words) == 0 else 1
-    padded = _pad_to(idx.astype(jnp.int32), 0, block_rows, -1)
-    n_words_padded = -(-n_words // tile_words) * tile_words
+    if bsz == 0:
+        return jnp.zeros((0, n_words), jnp.uint32)
     out = hash_build.hash_build_kernel(
-        padded,
+        _slots_t(idx),
         coeffs.astype(jnp.uint32),
         n_bins,
-        n_words=n_words_padded,
-        block_rows=block_rows,
-        tile_words=tile_words,
+        block_rows=min(block_rows, bsz),
+        tile_words=min(tile_words, n_words),
         interpret=interpret,
     )
-    return out[:bsz, :n_words]
+    return jax.lax.bitcast_convert_type(out, jnp.uint32)
 
 
 @functools.partial(
@@ -199,7 +211,7 @@ def band_hash(
     packed: jax.Array,
     n_bands: int,
     *,
-    block_rows: int = 8,
+    block_rows: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Packed (B, W) sketches -> (B, nb_eff) uint32 band keys.
@@ -209,9 +221,10 @@ def band_hash(
     xorshift-multiply chain (``core.packed.band_hash`` is the jnp oracle,
     bit-identical). ``n_bands`` clamps to W and the effective band count is
     ``nb_eff = ceil(W / wpb)`` — size bucket indexes off the output shape,
-    not the requested count. Pads rows to ``block_rows`` and the word axis
-    to ``nb_eff * wpb`` (zero pad words mix identically into every row's
-    key, so collisions are unaffected); crops rows on return.
+    not the requested count. The word axis is zero-padded to ``nb_eff *
+    wpb`` (zero pad words mix identically into every row's key, so
+    collisions are unaffected) and regrouped to the kernel's
+    (wpb, nb_eff, B) layout.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -221,14 +234,13 @@ def band_hash(
     n_bands = max(1, min(int(n_bands), w))
     wpb = -(-w // n_bands)
     nb_eff = -(-w // wpb)
-    src = _pad_to(packed, 0, block_rows, 0)
-    w_pad = nb_eff * wpb
-    if w_pad > w:
-        src = jnp.pad(src, ((0, 0), (0, w_pad - w)))
+    if bsz == 0:
+        return jnp.zeros((0, nb_eff), jnp.uint32)
+    src = _pad_to(packed, 1, wpb, 0).reshape(bsz, nb_eff, wpb).transpose(2, 1, 0)
     out = band_hash_mod.band_hash_kernel(
-        src, nb_eff, wpb, block_rows=block_rows, interpret=interpret
+        src, block_rows=min(block_rows, bsz), interpret=interpret
     )
-    return out[:bsz]
+    return out.T
 
 
 @functools.partial(
@@ -245,7 +257,7 @@ def sketch_score(
     b_fills: jax.Array | None = None,
     block_q: int = 128,
     block_c: int = 128,
-    block_w: int = 32,
+    block_w: int = 128,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Packed (Q, W) x (C, W) -> (Q, C) float32 similarity, fused epilogue.
@@ -255,7 +267,6 @@ def sketch_score(
     ``engine.SketchStore`` ingest-time cache — skips the O(C·W) corpus
     popcount per query); ``None`` computes them here in one cheap pass
     (O((Q+C) W) vs the kernel's O(Q C W)).
-    Zero-padded rows produce fill 0 -> similarity 0; cropped on return.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -263,27 +274,22 @@ def sketch_score(
         raise TypeError(f"packed sketches must be uint32, got {a.dtype}, {b.dtype}")
     q, w = a.shape
     c, _ = b.shape
-    block_q = min(block_q, max(8, q))
-    block_c = min(block_c, max(8, c))
+    if q == 0 or c == 0:
+        return jnp.zeros((q, c), jnp.float32)
     na = a_fills if a_fills is not None else pk.row_popcount(a)
     nb = b_fills if b_fills is not None else pk.row_popcount(b)
-    ap = _pad_to(a, 0, block_q, 0)
-    bp = _pad_to(b, 0, block_c, 0)
-    block_w = min(block_w, w) if w % min(block_w, w) == 0 else 1
-    ap = _pad_to(ap, 1, block_w, 0)
-    bp = _pad_to(bp, 1, block_w, 0)
-    nap = _pad_to(na.astype(jnp.int32), 0, block_q, 0)
-    nbp = _pad_to(nb.astype(jnp.int32), 0, block_c, 0)
-    out = popcount_sim.sketch_score_kernel(
-        ap, bp, nap, nbp, n_bins, measure,
-        block_q=block_q, block_c=block_c, block_w=block_w, interpret=interpret,
+    return popcount_sim.sketch_score_kernel(
+        a, b, na.astype(jnp.int32).reshape(q, 1), nb.astype(jnp.int32).reshape(1, c),
+        n_bins, measure,
+        block_q=min(block_q, _round_up(q, 8)), block_c=min(block_c, c),
+        block_w=block_w,
+        interpret=interpret,
     )
-    return out[:q, :c]
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_bins", "measure", "k", "block_q", "block_c", "sub_words",
+    static_argnames=("n_bins", "measure", "k", "block_q", "block_c", "block_w",
                      "interpret"),
 )
 def sketch_topk(
@@ -298,20 +304,22 @@ def sketch_topk(
     b_valid: jax.Array | None = None,
     block_q: int = 128,
     block_c: int = 128,
-    sub_words: int = 8,
+    block_w: int = 128,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Packed (Q, W) x (C, W) -> top-k (scores (Q, k), ids (Q, k)), fused.
 
     The streaming kernel (``topk_stream``) never materializes the (Q, C)
     score matrix: corpus blocks flow through VMEM once and only O(Q·k)
-    leaves the chip. Same padding/cropping contract as ``sketch_score``:
-    fill counts stream in (``a_fills``/``b_fills`` reuse the SketchStore
-    ingest-time cache, ``None`` popcounts here in one cheap pass), rows pad
-    to block multiples and crop on return. ``b_valid`` (C,) masks corpus
-    rows out of the result entirely. Rows come back sorted descending with
-    ``jax.lax.top_k``'s lowest-index-first tie-break; slots past the number
-    of retrievable docs (k > C, or masked rows) hold score -inf / id -1.
+    leaves the chip. Fill counts stream in as in ``sketch_score``
+    (``a_fills``/``b_fills`` reuse the SketchStore ingest-time cache,
+    ``None`` popcounts here in one cheap pass). ``b_valid`` (C,) masks
+    corpus rows out of the result entirely. ``block_c`` (rounded up to a
+    power of two, and to at least k) is both the corpus block and the
+    width of the kernel's running top-L. Rows come back sorted descending
+    with ``jax.lax.top_k``'s lowest-index-first tie-break; slots past the
+    number of retrievable docs (k > C, or masked rows) hold score -inf /
+    id -1.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -321,35 +329,23 @@ def sketch_topk(
         raise ValueError(f"k must be >= 1, got {k}")
     q, w = a.shape
     c, _ = b.shape
-    if c == 0:  # no docs: every slot is the empty sentinel
+    if c == 0 or q == 0:  # no docs: every slot is the empty sentinel
         return (jnp.full((q, k), -jnp.inf, jnp.float32),
                 jnp.full((q, k), -1, jnp.int32))
-    k_pad = topk_stream.next_pow2(k)
-    block_q = min(block_q, max(8, q))
-    # corpus block: a power of two (the sort network's lane count), big
-    # enough to donate a full k_pad columns, no bigger than the padded corpus
-    block_c = max(k_pad, min(topk_stream.next_pow2(block_c),
-                             topk_stream.next_pow2(max(c, 1))))
     na = a_fills if a_fills is not None else pk.row_popcount(a)
     nb = b_fills if b_fills is not None else pk.row_popcount(b)
-    valid = (
-        b_valid.astype(jnp.int32)
-        if b_valid is not None
-        else jnp.ones((c,), jnp.int32)
-    )
-    ap = _pad_to(a, 0, block_q, 0)
-    bp = _pad_to(b, 0, block_c, 0)
-    sub_w = min(sub_words, w)
-    ap = _pad_to(ap, 1, sub_w, 0)
-    bp = _pad_to(bp, 1, sub_w, 0)
-    nap = _pad_to(na.astype(jnp.int32), 0, block_q, 0)
-    nbp = _pad_to(nb.astype(jnp.int32), 0, block_c, 0)
-    validp = _pad_to(valid, 0, block_c, 0)
+    valid = (b_valid.astype(jnp.int32) if b_valid is not None
+             else jnp.ones((c,), jnp.int32))
     out_s, out_i = topk_stream.sketch_topk_kernel(
-        ap, bp, nap, nbp, validp, n_bins, measure, k_pad,
-        block_q=block_q, block_c=block_c, sub_words=sub_w, interpret=interpret,
+        a, b, na.astype(jnp.int32).reshape(q, 1),
+        nb.astype(jnp.int32).reshape(1, c), valid.reshape(1, c),
+        n_bins, measure,
+        block_q=min(block_q, _round_up(q, 8)),
+        block_c=max(topk_stream.next_pow2(block_c), topk_stream.next_pow2(k)),
+        block_w=block_w,
+        interpret=interpret,
     )
-    return out_s[:q, :k], out_i[:q, :k]
+    return out_s[:, :k], out_i[:, :k]
 
 
 def score_counts(a: jax.Array, b: jax.Array, **kw) -> jax.Array:

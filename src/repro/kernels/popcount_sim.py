@@ -5,83 +5,87 @@ W words per row):
 
     counts[q, c] = sum_w popcount( a[q, w] & b[c, w] )
 
-blocked (TQ, TC, TW) exactly like a tiled matmul — the word axis plays the
-contraction role, so the kernel inherits matmul-style arithmetic-intensity
-scaling: bytes/tile O(TQ*TW + TC*TW), work O(TQ*TC*TW). Popcount is SWAR
-(4 shift/mask stages + one byte-sum multiply), all VPU int32 lanes.
+blocked (TQ, TC) over queries and candidates, with the word axis as the
+contraction — matmul-style arithmetic-intensity scaling: bytes/tile
+O((TQ + TC) * W), work O(TQ * TC * W). Popcount is the VPU's native
+``population_count``.
 
-On the final word-tile the Alg 1/3/4 estimator epilogue (DESIGN.md §1) is
-applied in-register — fill counts |a_s|, |b_s| stream in as tiny
-per-row vectors — so the (Q, C) float similarity matrix leaves VMEM once.
+The contraction is an outer product per word (``_and_popcount_tile``):
+queries sit on sublanes and candidates on lanes, so word ``w`` contributes
+``popcount(a[:, w] & b[:, w]^T)`` — a (TQ, 1) lane-broadcast column ANDed
+with a (1, TC) sublane-broadcast row. Each TW-word tile of the candidate
+block is transposed once to make its word ``w`` a row. No 3-D
+intermediate, no gather. ``and_popcount`` walks the word axis in a loop of
+aligned TW-word tiles (plus one static tail tile), so the unrolled body
+does not grow with W.
 
-Grid: (Q/TQ, C/TC, W/TW); accumulation across the last (fastest) grid dim
-into the output tile, initialized at k == 0 (TPU grid order is row-major).
+The Alg 1/3/4 estimator epilogue (DESIGN.md §1) is applied in-register —
+fill counts |a_s| (TQ, 1) and |b_s| (1, TC) stream in as tiny per-row
+vectors — so the (Q, C) float similarity matrix leaves VMEM once.
 
-The contraction itself runs as an in-kernel loop over ``sub_w``-word
-sub-tiles (``_and_popcount_tile``), so the transient AND intermediate is
-(TQ, TC, sub_w) — 512 KiB at the defaults — instead of the full
-(TQ, TC, TW) 2 MiB 3D block the kernel used to materialize per step.
-VMEM per program (defaults TQ=TC=128, TW=32, sub_w=8):
-  a tile 128*32*4 = 16 KiB, b tile 16 KiB, AND sub-tile
-  128*128*8*4 = 512 KiB, acc tile 64 KiB  << 16 MiB.
+Grid: (Q/TQ, C/TC); each program holds whole (TQ, W) / (TC, W) rows.
+Trailing partial blocks are allowed: rows past Q or C only ever reach
+output slots that the out-of-bounds write drops.
+
+VMEM per program (defaults TQ=TC=TW=128, W=1090): a, b blocks 558 KiB
+each (double-buffered 2.2 MiB), transposed word tile 64 KiB, out 64 KiB
+<< 16 MiB.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["score_kernel", "sketch_score_kernel"]
+from ..core.estimators import cardinality_from_fill
 
-def _popcount(x):
-    # constants built inside the traced body (pallas kernels may not capture
-    # module-level device constants)
-    m1 = jnp.uint32(0x55555555)
-    m2 = jnp.uint32(0x33333333)
-    m4 = jnp.uint32(0x0F0F0F0F)
-    h01 = jnp.uint32(0x01010101)
-    x = x - ((x >> 1) & m1)
-    x = (x & m2) + ((x >> 2) & m2)
-    x = (x + (x >> 4)) & m4
-    return (x * h01) >> 24
+__all__ = ["and_popcount", "sketch_score_kernel"]
 
 
-def _and_popcount_tile(a, b, sub_w):
-    """(TQ, W) x (TC, W) uint32 -> (TQ, TC) int32 AND-popcounts.
+def _and_popcount_tile(a, b):
+    """(TQ, TW) x (TC, TW) uint32 -> (TQ, TC) int32 AND-popcounts.
 
-    Static loop over ``sub_w``-word sub-tiles: the transient AND block is
-    (TQ, TC, sub_w) instead of (TQ, TC, W), so VMEM pressure is set by the
-    sub-tile width, not the contraction length. W must divide into sub_w
-    chunks (callers pad the word axis).
-    """
-    w = a.shape[-1]
-    assert w % sub_w == 0, (w, sub_w)
+    One outer-product step per word: column ``w`` of ``a`` against row
+    ``w`` of ``b^T``."""
+    bt = b.T  # (TW, TC): word w of every candidate is one row
     acc = jnp.zeros((a.shape[0], b.shape[0]), jnp.int32)
-    for w0 in range(0, w, sub_w):
-        both = a[:, None, w0 : w0 + sub_w] & b[None, :, w0 : w0 + sub_w]
-        acc = acc + jnp.sum(_popcount(both).astype(jnp.int32), axis=-1)
+    for w in range(a.shape[1]):
+        both = a[:, w : w + 1] & bt[w : w + 1, :]
+        acc = acc + jax.lax.population_count(both).astype(jnp.int32)
     return acc
 
 
-def _cardinality(count, n_bins):
-    # ln(1 - c/N) / ln(1 - 1/N), fp32, clipped for full sketches
-    n = jnp.float32(n_bins)
-    c = jnp.clip(count.astype(jnp.float32), 0.0, n - 0.5)
-    inv_log_n = jnp.float32(1.0 / math.log1p(-1.0 / n_bins))
-    return (jnp.log(jnp.maximum(n - c, 0.5)) - jnp.float32(math.log(n_bins))) * inv_log_n
+def and_popcount(a_ref, b_ref, block_w):
+    """Whole-row (TQ, W) x (TC, W) refs -> (TQ, TC) int32 AND-popcounts:
+    a loop over aligned ``block_w``-word tiles plus one static tail tile,
+    so the unrolled body stays ``block_w`` words whatever W is."""
+    n_full, tail = divmod(a_ref.shape[1], block_w)
+
+    def body(k, acc):
+        off = pl.multiple_of(k * block_w, block_w)
+        return acc + _and_popcount_tile(a_ref[:, pl.ds(off, block_w)],
+                                        b_ref[:, pl.ds(off, block_w)])
+
+    acc = jnp.zeros((a_ref.shape[0], b_ref.shape[0]), jnp.int32)
+    if n_full:
+        acc = jax.lax.fori_loop(0, n_full, body, acc)
+    if tail:
+        lo = n_full * block_w
+        acc = acc + _and_popcount_tile(a_ref[:, lo:], b_ref[:, lo:])
+    return acc
 
 
 def _epilogue(counts, na, nb, n_bins, measure):
     """counts: (TQ, TC) int32 AND-popcounts; na: (TQ, 1); nb: (1, TC)."""
-    card_a = _cardinality(na, n_bins)
-    card_b = _cardinality(nb, n_bins)
-    union_s = na.astype(jnp.int32) + nb.astype(jnp.int32) - counts
-    card_u = _cardinality(union_s, n_bins)
+    if measure == "counts":
+        return counts.astype(jnp.float32)
+    card_a = cardinality_from_fill(na, n_bins)
+    card_b = cardinality_from_fill(nb, n_bins)
+    union_s = na + nb - counts
+    card_u = cardinality_from_fill(union_s, n_bins)
     ip = jnp.maximum(card_a + card_b - card_u, 0.0)
     if measure == "ip":
         return ip
@@ -94,27 +98,9 @@ def _epilogue(counts, na, nb, n_bins, measure):
     raise ValueError(f"unknown measure {measure!r}")
 
 
-def _kernel(a_ref, b_ref, na_ref, nb_ref, out_ref, acc_ref, *, n_bins, measure,
-            k_steps, sub_w):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    a = a_ref[...]  # (TQ, TW) uint32
-    b = b_ref[...]  # (TC, TW) uint32
-    acc_ref[...] += _and_popcount_tile(a, b, sub_w)
-
-    @pl.when(k == k_steps - 1)
-    def _fin():
-        counts = acc_ref[...]
-        if measure == "counts":
-            out_ref[...] = counts.astype(jnp.float32)
-        else:
-            na = na_ref[...].astype(jnp.int32).reshape(-1, 1)
-            nb = nb_ref[...].astype(jnp.int32).reshape(1, -1)
-            out_ref[...] = _epilogue(counts, na, nb, n_bins, measure)
+def _kernel(a_ref, b_ref, na_ref, nb_ref, out_ref, *, n_bins, measure, block_w):
+    out_ref[...] = _epilogue(and_popcount(a_ref, b_ref, block_w), na_ref[...],
+                             nb_ref[...], n_bins, measure)
 
 
 def sketch_score_kernel(
@@ -127,45 +113,31 @@ def sketch_score_kernel(
     *,
     block_q: int = 128,
     block_c: int = 128,
-    block_w: int = 32,
-    sub_words: int = 8,
+    block_w: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
     """(Q, W) x (C, W) packed sketches -> (Q, C) float32 similarity/counts.
 
-    ``na``/``nb`` are per-row fill counts (int32) — tiny, precomputed by a
-    single popcount pass in ``ops.sketch_score``. All dims must be multiples
-    of their block sizes (ops handles padding). ``sub_words`` is the width of
-    the in-kernel contraction sub-tile (clamped to divide ``block_w``).
+    ``na`` (Q, 1) / ``nb`` (1, C) are per-row int32 fill counts — tiny,
+    precomputed by a single popcount pass in ``ops.sketch_score``. Row
+    blocks need not divide Q or C: the grid covers trailing partial
+    blocks. ``block_w`` is the word tile of the in-kernel contraction loop
+    (a multiple of 128 on a TPU, or W itself).
     """
     q, w = a.shape
     c, _ = b.shape
-    assert q % block_q == 0 and c % block_c == 0 and w % block_w == 0, (q, c, w)
-    sub_w = min(sub_words, block_w)
-    while block_w % sub_w:
-        sub_w -= 1
-    k_steps = w // block_w
-    grid = (q // block_q, c // block_c, k_steps)
     return pl.pallas_call(
         functools.partial(
-            _kernel, n_bins=n_bins, measure=measure, k_steps=k_steps, sub_w=sub_w
+            _kernel, n_bins=n_bins, measure=measure, block_w=block_w
         ),
-        grid=grid,
+        grid=(pl.cdiv(q, block_q), pl.cdiv(c, block_c)),
         in_specs=[
-            pl.BlockSpec((block_q, block_w), lambda i, j, k: (i, k)),
-            pl.BlockSpec((block_c, block_w), lambda i, j, k: (j, k)),
-            pl.BlockSpec((block_q,), lambda i, j, k: (i,)),
-            pl.BlockSpec((block_c,), lambda i, j, k: (j,)),
+            pl.BlockSpec((block_q, w), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_c, w), lambda i, j: (j, 0)),
+            pl.BlockSpec((block_q, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, block_c), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((block_q, block_c), lambda i, j, k: (i, j)),
+        out_specs=pl.BlockSpec((block_q, block_c), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((q, c), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_q, block_c), jnp.int32)],
         interpret=interpret,
     )(a, b, na, nb)
-
-
-def score_kernel(a, b, **kw):
-    """AND-popcount counts only (no estimator epilogue)."""
-    na = jnp.zeros((a.shape[0],), jnp.int32)
-    nb = jnp.zeros((b.shape[0],), jnp.int32)
-    return sketch_score_kernel(a, b, na, nb, n_bins=1, measure="counts", **kw)

@@ -1,79 +1,86 @@
-"""Pallas TPU kernel: BinSketch construction as compare-reduce (no scatter).
+"""Pallas TPU kernel: BinSketch construction as compare-OR (no scatter).
 
 The paper's reference construction is a random scatter
 (``sketch[pi(i)] = 1``) — pathological on TPU. The TPU-native formulation
-(DESIGN.md §3): for a row-block of B vectors with pre-mapped padded bin ids
-``bins: (B, P)`` (pad = -1) and an output tile of TW packed words
-(= 32*TW sketch bins), compute
+(DESIGN.md §3) works per packed *word*: bin ``b`` sets bit ``b & 31`` of
+word ``b >> 5``, so for a tile of TW output words and a block of TB rows
 
-    hit[b, t] = any_p( bins[b, p] == bin_base + t ),   t in [0, 32*TW)
+    out[t, r] = OR_p ( (bins[p, r] >> 5) == word_base + t ? 1 << (bins[p, r] & 31) : 0 )
 
-as a broadcast-compare + OR-reduce on the VPU, then pack 32 bit-columns per
-uint32 word with a {1<<t} dot. Emits the sketch already packed, so the
-popcount scoring kernel reads 32x denser data.
+an OR-accumulation over the P bin slots of each row. Rows sit on lanes
+(the wrapper hands the bins in transposed, (P, B)), words on sublanes: one
+bin slot of all TB rows is a (1, TB) row, broadcast against the (TW, TB)
+word-index tile — a compare, a select and an OR per slot on the VPU, no
+reduction across lanes. Duplicate bins in a row OR into the same bit, so
+no dedupe is needed. Pads (-1) have word -1 and never match. The tile is
+transposed once at the end and written as (TB, TW) of the packed output.
 
-Grid: (rows / TB, words / TW). Each program touches a (TB, P) slab of bins
-(re-streamed per word-tile — bins are tiny next to the compare work) and
-writes a (TB, TW) uint32 tile.
+Grid: (rows / TB, words / TW), trailing partial blocks allowed (rows past
+B are dropped on write, words past W never match a real bin). Each program
+loops over the P bin slots eight at a time (an aligned (8, TB) load per
+iteration).
 
-VMEM budget per program (defaults TB=8, TW=16, P<=1024):
-  bins slab   8*1024*4 B                = 32 KiB
-  compare     8*1024*512 bool (staged)  = 4 MiB     << 16 MiB VMEM
-  out tile    8*16*4 B                  = 0.5 KiB
+VMEM per program (defaults TB=TW=128, P<=1024): bins 512 KiB
+(double-buffered), accumulator + word-index tile 128 KiB, out 64 KiB.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["build_sketch_kernel", "build_sketch"]
+__all__ = ["build_sketch_kernel", "or_pack_tile"]
+
+
+def or_pack_tile(bins_ref, tile_words: int, to_bins: Callable = lambda x: x):
+    """(P, TB) bin slots -> (TB, TW) packed int32 words of word-tile
+    ``program_id(1)``; ``to_bins`` maps a loaded (8, TB) slot block to bin
+    ids (-1 = pad). P must be a multiple of 8. Shared with ``hash_build``,
+    which computes the bins in-kernel."""
+    tb = bins_ref.shape[1]
+    word = (pl.program_id(1) * tile_words
+            + jax.lax.broadcasted_iota(jnp.int32, (tile_words, tb), 0))
+
+    def body(g, acc):
+        blk = to_bins(bins_ref[pl.ds(pl.multiple_of(g * 8, 8), 8), :])
+        for r in range(8):
+            b = blk[r : r + 1, :]
+            bit = jnp.left_shift(jnp.int32(1), b & 31)
+            acc = acc | jnp.where((b >> 5) == word, bit, 0)
+        return acc
+
+    acc = jax.lax.fori_loop(0, bins_ref.shape[0] // 8, body,
+                            jnp.zeros((tile_words, tb), jnp.int32))
+    return acc.T
 
 
 def _kernel(bins_ref, out_ref, *, tile_words: int):
-    j = pl.program_id(1)
-    bins = bins_ref[...]  # (TB, P) int32, pad = -1
-    n_bits = tile_words * 32
-    base = j * n_bits
-    # (TB, P, n_bits) compare; pads (-1) never equal a non-negative bin id.
-    targets = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, n_bits), 2)
-    hits = jnp.any(bins[:, :, None] == targets, axis=1)  # (TB, n_bits) bool
-    words = hits.reshape(bins.shape[0], tile_words, 32).astype(jnp.uint32)
-    weights = (jnp.uint32(1) << jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)).astype(
-        jnp.uint32
-    )
-    out_ref[...] = jnp.sum(words * weights, axis=-1).astype(jnp.uint32)
+    out_ref[...] = or_pack_tile(bins_ref, tile_words)
 
 
 def build_sketch_kernel(
-    bins: jax.Array,
-    n_bins: int,
+    bins_t: jax.Array,
+    n_words: int,
     *,
-    block_rows: int = 8,
-    tile_words: int = 16,
+    block_rows: int = 128,
+    tile_words: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    """``bins: (B, P)`` pre-mapped padded bin ids -> packed ``(B, W)`` uint32.
-
-    B must be a multiple of ``block_rows`` and ``ceil(n_bins/32)`` a multiple
-    of ``tile_words`` — ``ops.build_sketch`` handles padding/cropping.
-    """
-    bsz, _ = bins.shape
-    n_words = (n_bins + 31) // 32
-    assert bsz % block_rows == 0 and n_words % tile_words == 0, (bsz, n_words)
-    grid = (bsz // block_rows, n_words // tile_words)
+    """``bins_t: (P, B)`` transposed pre-mapped bin ids (pad -1, P a
+    multiple of 8) -> packed ``(B, n_words)`` int32 (bit-identical to the
+    uint32 sketch; ``ops.build_sketch`` transposes in and bitcasts out)."""
+    p, bsz = bins_t.shape
+    assert p % 8 == 0, p
+    grid = (pl.cdiv(bsz, block_rows), pl.cdiv(n_words, tile_words))
     return pl.pallas_call(
         functools.partial(_kernel, tile_words=tile_words),
         grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, bins.shape[1]), lambda i, j: (i, 0))],
+        in_specs=[pl.BlockSpec((p, block_rows), lambda i, j: (0, i))],
         out_specs=pl.BlockSpec((block_rows, tile_words), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((bsz, n_words), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((bsz, n_words), jnp.int32),
         interpret=interpret,
-    )(bins)
-
-
-def build_sketch(*args, **kwargs):  # convenience alias used by ops.py
-    return build_sketch_kernel(*args, **kwargs)
+    )(bins_t)

@@ -4,39 +4,40 @@
 reads it back just so ``jax.lax.top_k`` can keep k values per query — an
 O(Q·C) memory wall that caps corpus size. This kernel never materializes
 that matrix: the grid iterates corpus blocks as the *innermost* sequential
-dimension, each step computes the AND-popcount + estimator epilogue for its
-(TQ, TC) tile entirely in VMEM (reusing ``popcount_sim``'s SWAR popcount,
-sub-tiled contraction and ``_epilogue``) and merges the tile into a
-per-query running top-k of scores + *global* doc ids. Only (Q, k_pad)
-scores/ids ever leave the chip: HBM output shrinks from O(Q·C) to O(Q·k).
+dimension; each step computes the AND-popcount of its (TQ, TC) tile in VMEM
+(``popcount_sim.and_popcount``'s outer-product contraction), applies the
+estimator epilogue, and merges the tile into a
+per-query running top-L of scores + *global* doc ids. Only (Q, L) scores /
+ids ever leave the chip: HBM output shrinks from O(Q·C) to O(Q·L).
 
-Top-k maintenance is a sort-based compare-exchange network (DESIGN.md §7):
+Top-L maintenance is a sort-based compare-exchange network (DESIGN.md §7),
+with L = TC lanes (128, or the next power of two >= k):
 
-  * each (TQ, TC) score tile is bitonic-sorted descending along the lane
-    axis together with its doc ids (tie-break: smaller id, matching
-    ``jax.lax.top_k``), and its best ``k_pad`` columns kept;
-  * the running top-k (descending) concatenated with the reversed block
-    top-k is a bitonic sequence of length 2·k_pad, so one bitonic *merge*
-    (log2(2·k_pad) compare-exchange stages) re-sorts it; the best k_pad
-    survive in the output block, which stays VMEM-resident across the
-    corpus-block grid steps (same revisited-output pattern as a matmul
+  * each (TQ, TC) score tile is bitonic-sorted *ascending* along the lane
+    axis together with its doc ids (order: score, then smaller id wins —
+    ``jax.lax.top_k``'s tie-break);
+  * the running top-L is kept descending, so the lane-wise winner of the
+    running list and the ascending tile holds the L best of their union as
+    a bitonic sequence (the first half-cleaner of a bitonic merge); one
+    bitonic *merge* (log2(L) compare-exchange stages) re-sorts it
+    descending. The output block stays VMEM-resident across the
+    corpus-block grid steps (the revisited-output pattern of a matmul
     accumulator).
 
-Partner exchange at lane distance ``stride`` is the XOR trick laid out as a
-reshape: (TQ, L) -> (TQ, L/(2·stride), 2, stride) and a swap of the pair
-axis — pure VPU data movement, no gather.
+Partner exchange at lane distance ``stride`` is two lane rotations
+(``pltpu.roll``) and a select — the XOR partner of lane ``i`` is
+``i + stride`` where bit ``stride`` of ``i`` is clear, ``i - stride``
+where it is set.
 
-Invalid corpus rows (padding, masked docs) stream in via a per-row validity
-vector and score -inf with id -1, so they can never displace a real doc.
+Invalid corpus rows (masked docs, and the undefined rows of a trailing
+partial block) score -inf with id -1, so they can never displace a real doc.
 
-Grid: (Q/TQ, C/TC) with the corpus axis innermost; the word axis is not a
-grid dimension — each step loads its full (TQ, W) / (TC, W) word rows and
-contracts them with ``popcount_sim._and_popcount_tile``'s in-kernel sub-tile
-loop, keeping the AND transient at (TQ, TC, sub_w).
+Grid: (Q/TQ, C/TC) with the corpus axis innermost; each program holds
+whole (TQ, W) / (TC, W) rows.
 
-VMEM per program (TQ=TC=128, W=64, k_pad=16, sub_w=8):
-  a tile 32 KiB + b tile 32 KiB + AND sub-tile 512 KiB + score tile 64 KiB
-  + sort ids 64 KiB + running top-k 2*(128*16*4) = 16 KiB  << 16 MiB.
+VMEM per program (TQ=TC=TW=128, W=1090): a, b blocks 558 KiB each
+(double-buffered 2.2 MiB), running top-L scores + ids 128 KiB, sort
+temporaries a few hundred KiB  << 16 MiB.
 """
 
 from __future__ import annotations
@@ -46,8 +47,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .popcount_sim import _and_popcount_tile, _epilogue
+from .popcount_sim import _epilogue, and_popcount
 
 __all__ = ["sketch_topk_kernel", "next_pow2"]
 
@@ -62,39 +64,44 @@ def next_pow2(n: int) -> int:
     return p
 
 
-def _exchange(x, stride):
-    """Swap each lane with its partner at XOR-distance ``stride`` (last axis)."""
-    q, l = x.shape
-    x = x.reshape(q, l // (2 * stride), 2, stride)
-    x = jnp.concatenate([x[:, :, 1:2, :], x[:, :, 0:1, :]], axis=2)
-    return x.reshape(q, l)
+def _lane(shape):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _exchange(x, stride, low):
+    """Each lane's partner at XOR-distance ``stride`` (last axis); ``low``
+    marks lanes whose ``stride`` bit is clear."""
+    l = x.shape[-1]
+    up = pltpu.roll(x, l - stride, 1)  # up[i] = x[i + stride]
+    down = pltpu.roll(x, stride, 1)  # down[i] = x[i - stride]
+    return jnp.where(low, up, down)
+
+
+def _beats(s, ids, ps, pids):
+    """(s, ids) ranks before (ps, pids): score desc, then id asc — the id
+    tie-break reproduces ``jax.lax.top_k``'s lowest-index-first order."""
+    return (s > ps) | ((s == ps) & (ids <= pids))
 
 
 def _compare_exchange(s, ids, stride, take_max):
     """One compare-exchange stage on (score, id) pairs at lane distance
-    ``stride``. ``take_max`` marks lanes that keep the larger element under
-    the total order (score desc, id asc) — the id tie-break reproduces
-    ``jax.lax.top_k``'s lowest-index-first convention exactly."""
-    ps, pids = _exchange(s, stride), _exchange(ids, stride)
-    self_wins = (s > ps) | ((s == ps) & (ids <= pids))
-    keep_self = jnp.where(take_max, self_wins, ~self_wins)
+    ``stride``; ``take_max`` marks lanes that keep the better element."""
+    low = (_lane(s.shape) & stride) == 0
+    ps, pids = _exchange(s, stride, low), _exchange(ids, stride, low)
+    keep_self = take_max == _beats(s, ids, ps, pids)
     return jnp.where(keep_self, s, ps), jnp.where(keep_self, ids, pids)
 
 
-def _lane(shape, stride=None):
-    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return lane if stride is None else (lane & stride) == 0
-
-
-def _bitonic_sort_desc(s, ids):
-    """Full bitonic sort of (TQ, L) descending along lanes, L a power of 2."""
+def _bitonic_sort(s, ids, descending):
+    """Full bitonic sort of (TQ, L) along lanes, L a power of 2."""
     l = s.shape[-1]
+    lane = _lane(s.shape)
     size = 2
     while size <= l:
         stride = size // 2
         while stride >= 1:
-            desc_block = (_lane(s.shape) & size) == 0
-            lower = _lane(s.shape, stride)
+            desc_block = ((lane & size) == 0) == descending
+            lower = (lane & stride) == 0
             s, ids = _compare_exchange(s, ids, stride, lower == desc_block)
             stride //= 2
         size *= 2
@@ -103,16 +110,18 @@ def _bitonic_sort_desc(s, ids):
 
 def _bitonic_merge_desc(s, ids):
     """Merge a bitonic (TQ, L) sequence into descending order: one pass of
-    log2(L) compare-exchange stages, max kept at the lower lane."""
+    log2(L) compare-exchange stages, the better element kept at the lower
+    lane."""
+    lane = _lane(s.shape)
     stride = s.shape[-1] // 2
     while stride >= 1:
-        s, ids = _compare_exchange(s, ids, stride, _lane(s.shape, stride))
+        s, ids = _compare_exchange(s, ids, stride, (lane & stride) == 0)
         stride //= 2
     return s, ids
 
 
 def _kernel(a_ref, b_ref, na_ref, nb_ref, valid_ref, out_s_ref, out_i_ref, *,
-            n_bins, measure, sub_w, k_pad, block_c):
+            n_bins, measure, c, block_w):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -120,27 +129,19 @@ def _kernel(a_ref, b_ref, na_ref, nb_ref, valid_ref, out_s_ref, out_i_ref, *,
         out_s_ref[...] = jnp.full_like(out_s_ref, _NEG_INF)
         out_i_ref[...] = jnp.full_like(out_i_ref, -1)
 
-    a = a_ref[...]  # (TQ, W) uint32
-    b = b_ref[...]  # (TC, W) uint32
-    counts = _and_popcount_tile(a, b, sub_w)  # (TQ, TC) int32
-    if measure == "counts":
-        s = counts.astype(jnp.float32)
-    else:
-        na = na_ref[...].astype(jnp.int32).reshape(-1, 1)
-        nb = nb_ref[...].astype(jnp.int32).reshape(1, -1)
-        s = _epilogue(counts, na, nb, n_bins, measure)
-    valid = valid_ref[...].reshape(1, -1) != 0
-    s = jnp.where(valid, s, _NEG_INF)
-    ids = j * block_c + _lane(s.shape)  # global doc ids for this block
-    ids = jnp.where(valid, ids, -1)
-
-    # block top-k_pad, then one bitonic merge against the running top-k
-    s, ids = _bitonic_sort_desc(s, ids)
-    ms = jnp.concatenate([out_s_ref[...], s[:, k_pad - 1 :: -1]], axis=1)
-    mi = jnp.concatenate([out_i_ref[...], ids[:, k_pad - 1 :: -1]], axis=1)
-    ms, mi = _bitonic_merge_desc(ms, mi)
-    out_s_ref[...] = ms[:, :k_pad]
-    out_i_ref[...] = mi[:, :k_pad]
+    s = _epilogue(and_popcount(a_ref, b_ref, block_w), na_ref[...], nb_ref[...],
+                  n_bins, measure)
+    ids = j * s.shape[1] + _lane(s.shape)  # global doc ids of this block
+    ok = (valid_ref[...] != 0) & (ids < c)
+    s = jnp.where(ok, s, _NEG_INF)
+    ids = jnp.where(ok, ids, -1)
+    s, ids = _bitonic_sort(s, ids, descending=False)
+    run_s, run_i = out_s_ref[...], out_i_ref[...]
+    keep_run = _beats(run_s, run_i, s, ids)
+    s, ids = _bitonic_merge_desc(jnp.where(keep_run, run_s, s),
+                                 jnp.where(keep_run, run_i, ids))
+    out_s_ref[...] = s
+    out_i_ref[...] = ids
 
 
 def sketch_topk_kernel(
@@ -151,52 +152,42 @@ def sketch_topk_kernel(
     valid: jax.Array,
     n_bins: int,
     measure: str,
-    k_pad: int,
     *,
     block_q: int = 128,
     block_c: int = 128,
-    sub_words: int = 8,
+    block_w: int = 128,
     interpret: bool = False,
 ):
-    """(Q, W) x (C, W) packed sketches -> ((Q, k_pad) scores, (Q, k_pad) ids).
+    """(Q, W) x (C, W) packed sketches -> ((Q, L) scores, (Q, L) ids), L =
+    ``block_c``.
 
-    ``na``/``nb`` are per-row fill counts, ``valid`` (C,) int32 marks real
-    corpus rows (0 -> score -inf, id -1). Q/C/W must be multiples of their
-    block sizes and ``block_c``/``k_pad`` powers of two with
-    ``k_pad <= block_c`` (``ops.sketch_topk`` handles padding/clamping).
-    Output rows are sorted descending; HBM traffic is O(Q·(W + k_pad)), not
-    O(Q·C).
+    ``na`` (Q, 1) / ``nb`` (1, C) are per-row int32 fill counts, ``valid``
+    (1, C) int32 marks real corpus rows (0 -> score -inf, id -1).
+    ``block_c`` is a power of two: the width of the sort network and of the
+    running top-L. Row blocks need not divide Q or C; ``block_w`` is the
+    word tile of the contraction loop. Output rows are
+    sorted descending; HBM traffic is O(Q·W + C·W), output O(Q·L).
     """
     q, w = a.shape
     c, _ = b.shape
-    assert q % block_q == 0 and c % block_c == 0, (q, c, block_q, block_c)
-    assert block_c == next_pow2(block_c) and k_pad == next_pow2(k_pad)
-    assert k_pad <= block_c, (k_pad, block_c)
-    sub_w = min(sub_words, w)
-    while w % sub_w:
-        sub_w -= 1
-    grid = (q // block_q, c // block_c)
-    out_s, out_i = pl.pallas_call(
+    assert block_c == next_pow2(block_c), block_c
+    out_spec = pl.BlockSpec((block_q, block_c), lambda i, j: (i, 0))
+    return pl.pallas_call(
         functools.partial(
-            _kernel, n_bins=n_bins, measure=measure,
-            sub_w=sub_w, k_pad=k_pad, block_c=block_c,
+            _kernel, n_bins=n_bins, measure=measure, c=c, block_w=block_w,
         ),
-        grid=grid,
+        grid=(pl.cdiv(q, block_q), pl.cdiv(c, block_c)),
         in_specs=[
             pl.BlockSpec((block_q, w), lambda i, j: (i, 0)),
             pl.BlockSpec((block_c, w), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_q,), lambda i, j: (i,)),
-            pl.BlockSpec((block_c,), lambda i, j: (j,)),
-            pl.BlockSpec((block_c,), lambda i, j: (j,)),
+            pl.BlockSpec((block_q, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, block_c), lambda i, j: (0, j)),
+            pl.BlockSpec((1, block_c), lambda i, j: (0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_q, k_pad), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_q, k_pad), lambda i, j: (i, 0)),
-        ],
+        out_specs=[out_spec, out_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((q, k_pad), jnp.float32),
-            jax.ShapeDtypeStruct((q, k_pad), jnp.int32),
+            jax.ShapeDtypeStruct((q, block_c), jnp.float32),
+            jax.ShapeDtypeStruct((q, block_c), jnp.int32),
         ],
         interpret=interpret,
     )(a, b, na, nb, valid)
-    return out_s, out_i

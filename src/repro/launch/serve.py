@@ -17,12 +17,21 @@ planner onto a bounded set of jit shapes, sketched, and streamed through
 the fused top-k per segment. Reports build/mutate/serve throughput and
 recall@k against exact Jaccard over the *surviving* documents — the
 paper's ranking experiment (§IV-B) as a live, mutable service.
+
+Exit status: besides the probe and autopilot gates, a run that was not
+asked to inject faults (no ``--chaos``) exits non-zero when the engine's
+health shows a fault that a fallback served through (a degraded
+component, a failed / abandoned / quarantined job — ``health_faults``):
+the fallbacks keep answers correct, so without this gate a kernel the
+device refused would still exit 0.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +45,17 @@ import numpy as np
 from repro.obs.probe import exact_topk as exact_topk_jaccard  # noqa: E402
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class ServeRun:
+    """What a serve run leaves behind for a caller that drives it in-process
+    (``chip_smoke.py``): the engine and recall@k against exact Jaccard over
+    the surviving catalog."""
+
+    engine: Any  # repro.engine.SketchEngine
+    recall: Optional[float]
+
+
+def main(argv=None) -> ServeRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="tiny")
     ap.add_argument("--queries", type=int, default=64)
@@ -135,7 +154,8 @@ def main(argv=None):
                     help="after serving, run the online recall probe "
                          "(repro.obs.probe) over up to Q of the serve "
                          "queries on a supervised background job and report "
-                         "the probe.recall gauge (0 = off)")
+                         "the probe.recall gauge (0 = off); a probe that "
+                         "gets no reading exits nonzero")
     ap.add_argument("--probe-baseline", type=float, default=None,
                     help="expected probe recall; with --probe-tol this "
                          "turns the probe into a gate (nonzero exit on "
@@ -145,6 +165,9 @@ def main(argv=None):
                          "--probe-baseline")
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     chaos = args.chaos is not None and args.chaos > 0.0
     if chaos:
         if args.mutate_rate == 0.0:
@@ -227,6 +250,16 @@ def main(argv=None):
     print(f"build: {t_build:.2f}s ({n / t_build:.0f} docs/s, "
           f"backend={engine.backend.name}, fill cache primed at ingest)")
 
+    mesh = axis = None
+    if args.sharded:
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((len(jax.devices()),), ("data",))
+        axis = "data"
+        print(f"sharded serve: {len(jax.devices())} device(s)"
+              + (", segment-placed (resident slabs, head replicated)"
+                 if mutable else ", row-sliced single slab"))
+
     serve_now = None
     if mutable:
         # content per live doc id — mutations keep this in sync so the
@@ -258,6 +291,13 @@ def main(argv=None):
             # finds the job done
             engine.compact(background=True)
             stats = None
+        elif mesh is not None:
+            # sharded serving: place the sealed segments first, so the
+            # merge runs device-locally — one output segment per device
+            # (DESIGN.md §10) instead of one global slab on one device
+            engine.place(mesh, axis)
+            engine.compact(background=True)
+            stats = engine.wait_compaction()
         else:
             stats = engine.compact()
             if engine.store.sealed:
@@ -270,9 +310,9 @@ def main(argv=None):
             contents[int(g)] = fresh_idx[g]
             born[int(g)] = tick
         compacted = (f"compacted {stats['rows_in']}->{stats['rows_out']} rows"
-                     if stats else ("compaction deferred to chaos loop"
-                                    if chaos else
-                                    "compaction running in background"))
+                     if stats else "compaction deferred to chaos loop"
+                     if chaos else "compaction running in background"
+                     if args.background_compact else "nothing to compact")
         print(f"mutate: {len(dele)} deleted, {len(upd)} updated, sealed + "
               f"{compacted} in {t_mut:.2f}s "
               f"({n_mut / max(t_mut, 1e-9):.0f} mutations/s); "
@@ -363,13 +403,6 @@ def main(argv=None):
     q_pick = rng.choice(len(surv_ids), args.queries, replace=False)
     queries = surv_rows[q_pick]
 
-    mesh = axis = None
-    if args.sharded:
-        mesh = jax.make_mesh((len(jax.devices()),), ("data",))
-        axis = "data"
-        print(f"sharded serve: {len(jax.devices())} device(s)"
-              + (", segment-placed (resident slabs, head replicated)"
-                 if mutable else ", row-sliced single slab"))
 
     chaos_mgr = chaos_dir = chaos_plan = None
     chaos_saves = 0
@@ -557,7 +590,7 @@ def main(argv=None):
             got = pr.wait(now=serve_now)
             if got is None:
                 print("probe: ground-truth job failed — no reading")
-                probe_ok = args.probe_baseline is None
+                probe_ok = False
             else:
                 print(f"probe: recall@{pr.k} = {got:.3f} over "
                       f"{min(args.probe, len(queries))} queries "
@@ -572,7 +605,7 @@ def main(argv=None):
                           + ("" if probe_ok else " — GATE FAILED"))
         else:
             print("probe: launch refused (op quarantined) — no reading")
-            probe_ok = args.probe_baseline is None
+            probe_ok = False
 
     recall = None
     if args.check_recall:
@@ -600,7 +633,14 @@ def main(argv=None):
     if not autopilot_ok:
         raise SystemExit("autopilot segment-count gate failed "
                          "(see 'autopilot:' lines above)")
-    return recall
+    if not chaos:
+        from repro.engine import health_faults
+
+        found = health_faults(engine.health())
+        if found:
+            raise SystemExit("serve: faults recorded with no fault plan "
+                             "armed: " + "; ".join(found))
+    return ServeRun(engine, recall)
 
 
 if __name__ == "__main__":

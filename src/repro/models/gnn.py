@@ -31,7 +31,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..optim import adamw
-from ..parallel.sharding import RULES, logical_to_spec, shard_map
+from ..parallel.sharding import RULES, logical_to_spec
 from .layers import init_dense
 
 __all__ = ["SAGEConfig", "GraphSAGE", "NeighborSampler"]
@@ -113,7 +113,7 @@ class GraphSAGE:
             cnt = jax.lax.psum(cnt, axes)
             return agg / jnp.maximum(cnt, 1.0)[:, None]
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(), P(axes, None)),

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..parallel.sharding import axis_size, shard_map
 from .layers import init_dense, swiglu_apply
 
 __all__ = ["MoEConfig", "init_moe", "logical_moe", "moe_apply"]
@@ -89,7 +88,7 @@ def _local_moe(
     sharded over ff_axes in decode mode). Returns (y, aux_loss)."""
     t_loc, d = x.shape
     e_loc = w_gate.shape[0]
-    n_shards = axis_size(ep_axis)
+    n_shards = jax.lax.axis_size(ep_axis)
     e_total = e_loc * n_shards
     mi = jax.lax.axis_index(ep_axis)
     lo = mi * e_loc
@@ -167,7 +166,7 @@ def moe_apply(
     up_spec = P(ep_axis, None, ff_spec)
     down_spec = P(ep_axis, ff_spec, None)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda xs, wr, wg, wu, wd: _local_moe(
             xs, wr, wg, wu, wd, cfg=cfg, ep_axis=ep_axis, dp_axes=dp_axes, ff_axes=ff
         ),

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..optim import adamw
-from ..parallel.sharding import RULES, logical_to_spec, shard_map
+from ..parallel.sharding import RULES, logical_to_spec
 from .layers import init_dense
 
 __all__ = ["RecsysConfig", "RecsysModel", "criteo_like_vocabs"]
@@ -97,7 +97,7 @@ def sharded_embedding_lookup(
 
     ids_spec = P(dp_axes) if dp_axes else P(None)
     out_spec = P(dp_axes) if dp_axes else P(None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis, None), ids_spec),
@@ -393,7 +393,7 @@ class RecsysModel:
                 sc2, pos = jax.lax.top_k(sc_all, k_top)
                 return sc2, jnp.take_along_axis(ix_all, pos, axis=1)
 
-            return shard_map(
+            return jax.shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=(P(self.ep_axis, None), P(None, None)),
@@ -433,7 +433,7 @@ class RecsysModel:
             if fills is not None:
                 in_specs.append(P(ep))
                 operands.append(fills)
-            return shard_map(
+            return jax.shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=tuple(in_specs),

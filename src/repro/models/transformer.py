@@ -30,7 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..optim import adafactor, adamw
 from ..parallel.collectives import flash_combine
-from ..parallel.sharding import RULES, logical_to_spec, shard_map
+from ..parallel.sharding import RULES, logical_to_spec
 from . import moe as moe_lib
 from .layers import cross_entropy, flash_attention, init_dense, rms_norm, rope, swiglu_apply
 
@@ -541,7 +541,7 @@ class TransformerLM:
 
         if cfg.attn == "mla":
             kv_spec = logical_to_spec(cache_lg["ckv"][1:], mesh, self.rules)
-            local = shard_map(
+            local = jax.shard_map(
                 self._mla_decode_local,
                 mesh=mesh,
                 in_specs=(batch_spec, batch_spec, kv_spec, P(None, None), P()),
@@ -550,7 +550,7 @@ class TransformerLM:
             )
         else:
             kv_spec = logical_to_spec(cache_lg["k"][1:], mesh, self.rules)
-            local = shard_map(
+            local = jax.shard_map(
                 self._gqa_decode_local,
                 mesh=mesh,
                 in_specs=(batch_spec, batch_spec, batch_spec, kv_spec, kv_spec, P()),

@@ -31,13 +31,14 @@ __all__ = ["RecallProbe", "exact_topk"]
 def exact_topk(corpus_idx, query_idx, k):
     """Host-side exact Jaccard top-k (ground truth; small query sets).
 
-    Vectorized membership-matrix formulation: |q ∩ c| is a (Q, d) x (d, C)
-    matmul over {0,1} membership rows and |q ∪ c| follows by
-    inclusion-exclusion — no per-pair Python set loop (which dominated
-    serve-demo wall time at a few thousand docs). The corpus membership
-    matrix is built per column-chunk so peak memory stays ~64 MB however
-    large C·d grows (nytimes: C=5000, d=102660 would be a 2 GB dense
-    matrix otherwise); only the (Q, C) sims matrix is held whole.
+    Rows are sets: distinct indices padded with -1, as ``data.synthetic``
+    emits them. Only words that some query holds can intersect, so the
+    membership columns are the queries' vocabulary V, not all d words:
+    |q ∩ c| is a (Q, V) x (V, C) matmul over {0,1} membership rows, |c| is
+    the row's index count, and |q ∪ c| follows by inclusion-exclusion — no
+    per-pair Python set loop. The corpus membership matrix is built per row
+    chunk so peak memory stays ~64 MB however large C grows; only the
+    (Q, C) sims matrix is held whole.
 
     Returns (Q, k) *positions* into ``corpus_idx`` (score desc, position
     asc on ties) — callers map positions to global ids themselves.
@@ -45,24 +46,26 @@ def exact_topk(corpus_idx, query_idx, k):
     corpus_idx = np.asarray(corpus_idx)
     query_idx = np.asarray(query_idx)
     d = int(max(corpus_idx.max(initial=0), query_idx.max(initial=0))) + 1
+    vocab = np.unique(query_idx[query_idx >= 0])
+    col = np.full(d, -1, np.int64)
+    col[vocab] = np.arange(len(vocab))
 
     def member(idx):
-        m = np.zeros((idx.shape[0], d), np.float32)
-        rows = np.repeat(np.arange(idx.shape[0]), idx.shape[1])
-        flat = idx.ravel()
-        keep = flat >= 0
-        m[rows[keep], flat[keep]] = 1.0
+        c = np.where(idx >= 0, col[np.maximum(idx, 0)], -1)
+        m = np.zeros((idx.shape[0], max(len(vocab), 1)), np.float32)
+        rows, slots = np.nonzero(c >= 0)
+        m[rows, c[rows, slots]] = 1.0
         return m
 
     qm = member(query_idx)
     q_sizes = qm.sum(axis=1)[:, None]
-    c_chunk = max(1, (1 << 24) // d)  # ~64 MB of float32 membership per chunk
+    c_chunk = max(1, (1 << 24) // qm.shape[1])  # ~64 MB of membership
     sims = np.empty((len(query_idx), len(corpus_idx)), np.float32)
     for lo in range(0, len(corpus_idx), c_chunk):
-        cm = member(corpus_idx[lo : lo + c_chunk])
-        inter = qm @ cm.T  # float32 matmul is exact for counts << 2^24
-        union = q_sizes + cm.sum(axis=1)[None, :] - inter
-        sims[:, lo : lo + cm.shape[0]] = inter / np.maximum(union, 1.0)
+        chunk = corpus_idx[lo : lo + c_chunk]
+        inter = qm @ member(chunk).T  # float32 matmul is exact for counts << 2^24
+        union = q_sizes + (chunk >= 0).sum(axis=1)[None, :] - inter
+        sims[:, lo : lo + len(chunk)] = inter / np.maximum(union, 1.0)
     return np.argsort(-sims, axis=1, kind="stable")[:, :k]
 
 

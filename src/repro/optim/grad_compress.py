@@ -22,7 +22,6 @@ from typing import Any, Callable, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..parallel.sharding import axis_size
 
 __all__ = ["quantize_leaf", "dequantize_leaf", "init_error", "compress_grads", "make_compressed_psum"]
 
@@ -86,7 +85,7 @@ def make_compressed_psum(axis_names: Sequence[str]) -> Callable:
         summed = jax.tree.map(lambda c: jax.lax.psum(c.astype(jnp.int32), names), codes)
         n_shards = 1
         for a in names:
-            n_shards *= axis_size(a)
+            n_shards *= jax.lax.axis_size(a)
         synced = jax.tree.map(
             lambda c, s: (c.astype(jnp.float32) * s) / n_shards, summed, scale
         )

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .sharding import axis_size, shard_map
 
 __all__ = ["split_kv_decode_attention", "flash_combine", "ring_matmul"]
 
@@ -75,7 +74,7 @@ def ring_matmul(x: jax.Array, w_shard: jax.Array, axis: str) -> jax.Array:
     ``(idx - t) mod n_dev`` and writes column block ``origin * N_loc``.
     Output: (B_loc, n_dev * N_loc) = x @ W. Call under shard_map.
     """
-    n_dev = axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
     n_loc = w_shard.shape[1]
@@ -102,7 +101,7 @@ def make_sp_decode(mesh: Mesh, axis: str = "data"):
     def fn(q, k, v, scale):
         return split_kv_decode_attention(q, k, v, axis, scale)
 
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(), P(None, axis, None, None), P(None, axis, None, None), None),

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .sharding import shard_map
 
 __all__ = ["pipeline_apply"]
 
@@ -77,7 +76,7 @@ def pipeline_apply(
         out = jax.lax.psum(jnp.where(stage == n_stage - 1, out, jnp.zeros_like(out)), axis)
         return out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(axis), P()),

@@ -28,38 +28,12 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
-    "RULES", "axis_size", "logical_to_spec", "named_sharding", "shard_put",
-    "tree_shardings", "shard_map",
+    "RULES", "logical_to_spec", "named_sharding", "shard_put",
+    "tree_shardings",
 ]
 
 PyTree = Any
 
-
-def axis_size(axis: str) -> int:
-    """Static size of a named mesh axis, from inside shard_map, across jax
-    versions (``jax.lax.axis_size`` is new; 0.4.x spells it
-    ``jax.core.axis_frame(name)``)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.core.axis_frame(axis)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions (new-API keyword signature).
-
-    jax >= 0.6 exposes ``jax.shard_map(..., check_vma=)``; older releases
-    only have ``jax.experimental.shard_map.shard_map(..., check_rep=)``.
-    All shard_map call sites in this repo go through here.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
 
 def shard_put(arr: jax.Array, mesh: Mesh, spec: P) -> jax.Array:
     """Pin an array onto a mesh with an explicit PartitionSpec, once.

@@ -336,12 +336,13 @@ def test_prefilter_placed_sliced_single_agreement(multidevice):
     """Mixed-width store on an 8-device mesh: the prefiltered placed path,
     the prefiltered single-device path, and both exhaustive paths agree
     (prefilter == prefilter, exhaustive == exhaustive, scores identical
-    for shared ids)."""
+    for shared ids) over a batch of several planner chunks."""
     multidevice(
         """
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import BinSketchConfig, make_mapping
 from repro.engine import BandPolicy, DistillPolicy, QueryPlanner, SketchEngine
+from repro.launch.mesh import make_mesh
 
 rng = np.random.default_rng(0)
 d, nnz = 2048, 32
@@ -361,12 +362,13 @@ eng.delete(list(range(0, 240, 13)))
 eng.distill(DistillPolicy(widths=(128,)), background=False)  # mixed width
 eng.add(jnp.asarray(docs[:5]))  # replicated head rows on top
 
-pick = rng.choice(240, 12, replace=False)
+# 40 queries: three planner chunks, each with its own candidate union
+pick = rng.choice(240, 40, replace=False)
 q_np = docs[pick].copy()
-q_np[np.arange(12), rng.integers(0, nnz, 12)] = rng.integers(0, d, 12)
+q_np[np.arange(40), rng.integers(0, nnz, 40)] = rng.integers(0, d, 40)
 q = jnp.asarray(np.sort(q_np, axis=1))
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 s_sp, i_sp = map(np.asarray, eng.query(q, 10, prefilter=True))
 s_se, i_se = map(np.asarray, eng.query(q, 10, prefilter=False))
 s_pp, i_pp = map(np.asarray, eng.query_sharded(mesh, "data", q, 10, prefilter=True))

@@ -367,6 +367,21 @@ def test_band_build_failure_at_seal_degrades_not_raises():
     assert_topk_equivalent(eng.query(q, 5), ref.query(q, 5))
 
 
+def test_serve_exits_nonzero_on_fault_without_chaos():
+    """A band-hash build failure degrades the sealed segment to unindexed
+    and serving stays exact — but a serve run that was not asked to inject
+    faults (no --chaos) must not exit 0 over it: the fallback would
+    otherwise hide a real fault, such as a kernel the device refused."""
+    from repro.launch import serve
+
+    with faults.scoped(faults.FaultPlan(
+        {"band.build": faults.FaultSpec("raise")}
+    )):
+        with pytest.raises(SystemExit, match="degraded band_index"):
+            serve.main(["--dataset", "tiny", "--prefilter", "--queries", "8",
+                        "--backend", "oracle"])
+
+
 def test_placement_failure_falls_back_to_sliced_path():
     """placement.build raising: query_sharded serves through the sliced
     exhaustive path — same results — and records the degradation."""

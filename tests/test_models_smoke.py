@@ -14,7 +14,9 @@ MESH = None
 def mesh():
     global MESH
     if MESH is None:
-        MESH = jax.make_mesh((1, 1), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+
+        MESH = make_mesh((1, 1), ("data", "model"))
     return MESH
 
 

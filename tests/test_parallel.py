@@ -45,12 +45,11 @@ def test_ring_matmul_and_sp_decode(multidevice):
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.parallel import collectives
-from repro.parallel.sharding import shard_map
 mesh = jax.make_mesh((8,), ("data",))
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.normal(size=(16, 32)), jnp.float32)
 w = jnp.asarray(rng.normal(size=(32, 64)), jnp.float32)
-ring = shard_map(lambda xs, ws: collectives.ring_matmul(xs, ws, "data"),
+ring = jax.shard_map(lambda xs, ws: collectives.ring_matmul(xs, ws, "data"),
                      mesh=mesh, in_specs=(P("data", None), P(None, "data")),
                      out_specs=P("data", None), check_vma=False)
 np.testing.assert_allclose(np.asarray(ring(x, w)), np.asarray(x @ w), rtol=1e-5, atol=1e-5)
@@ -111,14 +110,13 @@ def test_grad_compression_and_compressed_psum(multidevice):
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.optim import grad_compress as gc
-from repro.parallel.sharding import shard_map
 mesh = jax.make_mesh((8,), ("data",))
 sync = gc.make_compressed_psum(("data",))
 g = jnp.asarray(np.random.default_rng(1).normal(size=(8, 32)), jnp.float32)
 def f(gs, es):
     out, e2 = sync({"g": gs}, {"g": es})
     return out["g"], e2["g"]
-fn = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+fn = jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
                    out_specs=(P(), P("data")), check_vma=False)
 synced, err = fn(g, jnp.zeros_like(g))
 want = np.asarray(g).mean(0)  # mean over shards (each shard = one row)

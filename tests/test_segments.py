@@ -184,6 +184,24 @@ def test_segmented_add_matches_sketchstore():
     np.testing.assert_array_equal(np.asarray(seg.fills), np.asarray(plain.fills))
 
 
+def test_head_capacity_capped_at_seal_rows():
+    """An auto-sealing head is allocated at ``seal_rows`` rows however large
+    the requested capacity (its u16 counters cost 2·N bytes per row), keeps
+    that size across seals, and the store answers exactly like a fresh
+    build over the same docs."""
+    cfg, mapping, idx = _fixture()
+    engine = SketchEngine.build(cfg, mapping, backend="oracle", mutable=True,
+                                capacity=100_000, seal_rows=16)
+    head = engine.store.head
+    assert head.capacity == 16 and head.counters.shape == (16, cfg.n_bins)
+    for lo in range(0, 60, 8):
+        engine.add(jnp.asarray(idx[lo : min(lo + 8, 60)]))
+    assert len(engine.store.sealed) == 3 and engine.store.head.size == 12
+    assert engine.store.head.capacity == 16
+    _shadow_equal(engine, {i: idx[i] for i in range(60)},
+                  backends=("oracle", "pallas-interpret"))
+
+
 def test_add_sketches_and_merge_by_id():
     cfg, mapping, idx = _fixture()
     base = SketchStore.from_indices(cfg, mapping, jnp.asarray(idx[:8]))

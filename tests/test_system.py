@@ -49,8 +49,38 @@ def test_dedup_finds_planted_duplicates():
 def test_serve_driver_runs_with_recall():
     from repro.launch import serve
 
-    recall = serve.main(["--dataset", "tiny", "--queries", "16", "--topk", "5"])
+    recall = serve.main(["--dataset", "tiny", "--queries", "16", "--topk", "5"]).recall
     assert recall is not None and recall > 0.3
+
+
+def test_chip_smoke_refuses_cpu():
+    """Off a TPU the chip smoke exits non-zero with a one-line reason before
+    any phase runs, and prints no verdict line."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, os.path.join(repo, "chip_smoke.py")],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr
+    assert '"ok"' not in r.stdout and "[" not in r.stdout
+
+
+def test_compile_cache_location(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, where set, is left to jax; otherwise the
+    persistent cache goes to the fixed ``<checkout>/.jax_cache``."""
+    from repro.launch import cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert cache.enable_compile_cache() == str(cache.DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(cache.DEFAULT_DIR)
+        assert (cache.DEFAULT_DIR.parent / "chip_smoke.py").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
 
 
 def test_train_loop_checkpoint_restart(tmp_path):
@@ -105,7 +135,8 @@ def test_dryrun_cell_small_mesh(multidevice):
 import jax, numpy as np
 from repro.configs import get
 from repro.launch.hlo_analysis import analyze
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 spec = get("deepseek-v2-lite-16b")
 b = spec.build(mesh, shape_name="train_4k", smoke=True)
 args = b["inputs"]("train_4k")
