@@ -36,9 +36,9 @@ queries exactly the N'-sketches of the raw queries.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-import time
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, Iterator, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +50,7 @@ from .. import obs
 from ..core import binsketch
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
+from ..obs.trace import span, stage
 from . import backends as backends_mod
 from .backends import Backend
 from .banding import BandPolicy
@@ -163,6 +164,9 @@ class SketchEngine:
     controller: Optional[object] = dataclasses.field(
         default=None, init=False, repr=False
     )
+    # query calls so far, the ``call`` stat of each ``repro.engine.query``
+    # span: it tells one call's spans from the next in a profile
+    _calls: int = dataclasses.field(default=0, init=False, repr=False)
 
     # ------------------------------------------------------------ construct
     @classmethod
@@ -541,12 +545,9 @@ class SketchEngine:
         ]
         if len(parts) == 1:
             return parts[0]
-        t0 = time.perf_counter() if tr is not None else 0.0
-        got = merge_segment_topk([p[0] for p in parts],
-                                 [p[1] for p in parts], k)
-        if tr is not None:
-            tr.add_stage("merge", time.perf_counter() - t0)
-        return got
+        with stage(tr, "merge"):
+            return merge_segment_topk([p[0] for p in parts],
+                                      [p[1] for p in parts], k)
 
     def _view_part(
         self, qs: jax.Array, v: SegmentView, k: int, *,
@@ -556,14 +557,13 @@ class SketchEngine:
         local indices mapped to global doc ids."""
         nb = v.n_bins if v.n_bins is not None else self.cfg.n_bins
         q_w = self._rebucket_queries(qs, nb, width_cache)
-        t0 = time.perf_counter() if tr is not None else 0.0
-        sc, ix = self.backend.topk(
-            q_w, v.sketches, nb, self.measure, k,
-            corpus_fills=v.fills if use_fill_cache else None,
-            corpus_valid=v.valid,
-        )
+        with stage(tr, "kernel_score"):
+            sc, ix = self.backend.topk(
+                q_w, v.sketches, nb, self.measure, k,
+                corpus_fills=v.fills if use_fill_cache else None,
+                corpus_valid=v.valid,
+            )
         if tr is not None:
-            tr.add_stage("kernel_score", time.perf_counter() - t0)
             tr.note_width(nb)
         if v.ids is not None:
             ix = jnp.where(ix >= 0, jnp.take(v.ids, jnp.maximum(ix, 0)), -1)
@@ -642,24 +642,21 @@ class SketchEngine:
         same fills)."""
         nb = seg.n_bins if seg.n_bins is not None else self.cfg.n_bins
         q_w = self._rebucket_queries(qs, nb, width_cache)
-        t0 = time.perf_counter() if tr is not None else 0.0
         n = len(cand)
-        padded = self.planner.candidate_bucket(n, seg.n_rows)
-        rows_np = np.zeros(padded, np.int32)
-        rows_np[:n] = cand
-        rows_dev = jnp.asarray(rows_np)
-        sub = jnp.take(seg.sketches, rows_dev, axis=0)
-        fills = jnp.take(seg.fills, rows_dev) if use_fill_cache else None
-        vmask = jnp.asarray((np.arange(padded) < n).astype(np.int32))
+        with stage(tr, "candidate_gather"):
+            padded = self.planner.candidate_bucket(n, seg.n_rows)
+            rows_np = np.zeros(padded, np.int32)
+            rows_np[:n] = cand
+            rows_dev = jnp.asarray(rows_np)
+            sub = jnp.take(seg.sketches, rows_dev, axis=0)
+            fills = jnp.take(seg.fills, rows_dev) if use_fill_cache else None
+            vmask = jnp.asarray((np.arange(padded) < n).astype(np.int32))
+        with stage(tr, "kernel_score"):
+            sc, ix = self.backend.topk(
+                q_w, sub, nb, self.measure, k,
+                corpus_fills=fills, corpus_valid=vmask,
+            )
         if tr is not None:
-            tr.add_stage("candidate_gather", time.perf_counter() - t0)
-            t0 = time.perf_counter()
-        sc, ix = self.backend.topk(
-            q_w, sub, nb, self.measure, k,
-            corpus_fills=fills, corpus_valid=vmask,
-        )
-        if tr is not None:
-            tr.add_stage("kernel_score", time.perf_counter() - t0)
             tr.note_width(nb)
         gids = np.full(padded, -1, np.int64)
         gids[:n] = seg.ids[cand]
@@ -691,13 +688,11 @@ class SketchEngine:
                 )
             else:
                 nb = seg.n_bins if seg.n_bins is not None else self.cfg.n_bins
-                t0 = time.perf_counter() if tr is not None else 0.0
-                qkeys = self._query_band_keys(
-                    qs, nb, rows, width_cache, qkeys_cache
-                )
-                cand = self._segment_candidates(seg, qkeys, now, tr=tr)
-                if tr is not None:
-                    tr.add_stage("band_lookup", time.perf_counter() - t0)
+                with stage(tr, "band_lookup"):
+                    qkeys = self._query_band_keys(
+                        qs, nb, rows, width_cache, qkeys_cache
+                    )
+                    cand = self._segment_candidates(seg, qkeys, now, tr=tr)
                 stats["seg_rows"] += seg.n_rows
                 if cand is None:
                     stats["exhaustive_segments"] += 1
@@ -738,11 +733,8 @@ class SketchEngine:
                     jnp.full((qs.shape[0], k), -1, jnp.int32))
         if len(parts_s) == 1:
             return parts_s[0], parts_i[0]
-        t0 = time.perf_counter() if tr is not None else 0.0
-        got = merge_segment_topk(parts_s, parts_i, k)
-        if tr is not None:
-            tr.add_stage("merge", time.perf_counter() - t0)
-        return got
+        with stage(tr, "merge"):
+            return merge_segment_topk(parts_s, parts_i, k)
 
     def _resolve_prefilter(self, prefilter: Optional[bool]) -> bool:
         on = (isinstance(self.store, SegmentedStore)
@@ -802,23 +794,18 @@ class SketchEngine:
             self.store.poll_compaction()  # adopt a finished background merge
         banded = self._resolve_prefilter(prefilter)
         n_q = int(query_idx.shape[0])
-        obs_metrics.inc("query.calls")
-        obs_metrics.inc("query.rows", n_q)
-        tr = obs_trace.start("query", n_q, k)
-        try:
+        with self._query_call("query", n_q, k) as tr:
             out_s, out_i = [], []
             views = None if banded else self.store.segment_views(now=now)
             stats = self._fresh_prefilter_stats() if banded else None
             width_cache: dict = {}
             qkeys_cache: dict = {}
             for chunk in self.planner.plan(n_q):
-                t0 = time.perf_counter() if tr is not None else 0.0
-                qs = self._padded_query_sketches(
-                    query_idx[chunk.start : chunk.start + chunk.rows],
-                    chunk.padded,
-                )
-                if tr is not None:
-                    tr.add_stage("rebucket", time.perf_counter() - t0)
+                with stage(tr, "rebucket"):
+                    qs = self._padded_query_sketches(
+                        query_idx[chunk.start : chunk.start + chunk.rows],
+                        chunk.padded,
+                    )
                 if banded:
                     try:
                         sc, ix = self._prefiltered_topk(
@@ -861,6 +848,19 @@ class SketchEngine:
                     tr.k_overflow = True
             return (jnp.concatenate(out_s, axis=0),
                     jnp.concatenate(out_i, axis=0))
+
+    @contextlib.contextmanager
+    def _query_call(self, path: str, n_q: int, k: int) -> Iterator:
+        """One query call: the exact ``query.calls`` / ``query.rows``
+        counters, the ``repro.engine.query`` span, and the sampled trace
+        (None when unsampled or disarmed) for the body to fill."""
+        obs_metrics.inc("query.calls")
+        obs_metrics.inc("query.rows", n_q)
+        self._calls += 1
+        tr = obs_trace.start(path, n_q, k)
+        try:
+            with span("engine.query", rows=n_q, k=k, path=path, call=self._calls):
+                yield tr
         finally:
             obs_trace.finish(tr)
 
@@ -899,10 +899,7 @@ class SketchEngine:
         """
         now = self._auto_now(now)
         n_q = int(query_idx.shape[0])
-        obs_metrics.inc("query.calls")
-        obs_metrics.inc("query.rows", n_q)
-        tr = obs_trace.start("query_sharded", n_q, k)
-        try:
+        with self._query_call("query_sharded", n_q, k) as tr:
             if k > self.store.size:
                 obs_metrics.inc("query.k_overflow")
                 if tr is not None:
@@ -918,13 +915,11 @@ class SketchEngine:
                         stats = self._fresh_prefilter_stats() if pf else None
                         out_s, out_i = [], []
                         for chunk in self.planner.plan(n_q):
-                            t0 = time.perf_counter() if tr is not None else 0.0
-                            qs = self._padded_query_sketches(
-                                query_idx[chunk.start : chunk.start + chunk.rows],
-                                chunk.padded,
-                            )
-                            if tr is not None:
-                                tr.add_stage("rebucket", time.perf_counter() - t0)
+                            with stage(tr, "rebucket"):
+                                qs = self._padded_query_sketches(
+                                    query_idx[chunk.start : chunk.start + chunk.rows],
+                                    chunk.padded,
+                                )
                             sc, ix = self._query_placed(
                                 mesh, axis, qs, chunk.rows, k, now=now,
                                 stats=stats, tr=tr,
@@ -945,36 +940,28 @@ class SketchEngine:
                             tr.note_degraded("placement")
                         self._placement = None
             views = self.store.segment_views(now=now)
-            t0 = time.perf_counter() if tr is not None else 0.0
-            qs = self._sketch_queries(query_idx)
-            if tr is not None:
-                tr.add_stage("rebucket", time.perf_counter() - t0)
+            with stage(tr, "rebucket"):
+                qs = self._sketch_queries(query_idx)
             if not views:
                 return (jnp.full((qs.shape[0], k), -jnp.inf, jnp.float32),
                         jnp.full((qs.shape[0], k), -1, jnp.int32))
             self._count_view_hits()
             cache: dict = {}
-            t0 = time.perf_counter() if tr is not None else 0.0
-            parts = [
-                self._sharded_view_topk(mesh, axis, qs, v, k, width_cache=cache)
-                for v in views
-            ]
+            with stage(tr, "kernel_score"):
+                parts = [
+                    self._sharded_view_topk(mesh, axis, qs, v, k, width_cache=cache)
+                    for v in views
+                ]
             if tr is not None:
-                tr.add_stage("kernel_score", time.perf_counter() - t0)
                 for v in views:
                     tr.note_width(v.n_bins if v.n_bins is not None
                                   else self.cfg.n_bins)
             if len(parts) == 1:
                 return parts[0]
-            t0 = time.perf_counter() if tr is not None else 0.0
-            got = merge_segment_topk(
-                [p[0] for p in parts], [p[1] for p in parts], k
-            )
-            if tr is not None:
-                tr.add_stage("merge", time.perf_counter() - t0)
-            return got
-        finally:
-            obs_trace.finish(tr)
+            with stage(tr, "merge"):
+                return merge_segment_topk(
+                    [p[0] for p in parts], [p[1] for p in parts], k
+                )
 
     def place(self, mesh: Mesh, axis: str) -> SegmentPlacement:
         """Place the sealed segments on ``mesh`` now (``query_sharded``
@@ -1071,19 +1058,17 @@ class SketchEngine:
         counts share jit traces. Per-device slots ascend, so the gathered
         sub-slab keeps the slab's id-ascending tie-break order."""
         measure, backend = self.measure, self.backend
-        t0 = time.perf_counter() if tr is not None else 0.0
-        dev = slots // slab.n_local
-        loc = slots % slab.n_local
-        counts = np.bincount(dev, minlength=n_devices)
-        l_c = self.planner.candidate_bucket(int(counts.max()), slab.n_local)
-        idx = np.zeros((n_devices, l_c), np.int32)
-        msk = np.zeros((n_devices, l_c), np.int32)
-        for d in range(n_devices):
-            ld = loc[dev == d]  # ascending: slots are globally sorted
-            idx[d, : len(ld)] = ld
-            msk[d, : len(ld)] = 1
-        if tr is not None:
-            tr.add_stage("candidate_gather", time.perf_counter() - t0)
+        with stage(tr, "candidate_gather"):
+            dev = slots // slab.n_local
+            loc = slots % slab.n_local
+            counts = np.bincount(dev, minlength=n_devices)
+            l_c = self.planner.candidate_bucket(int(counts.max()), slab.n_local)
+            idx = np.zeros((n_devices, l_c), np.int32)
+            msk = np.zeros((n_devices, l_c), np.int32)
+            for d in range(n_devices):
+                ld = loc[dev == d]  # ascending: slots are globally sorted
+                idx[d, : len(ld)] = ld
+                msk[d, : len(ld)] = 1
 
         def local(q_rep, sl, fills, ids, idx_loc, idx_valid, nb=slab.n_bins):
             sub = jnp.take(sl, idx_loc, axis=0)
@@ -1107,14 +1092,11 @@ class SketchEngine:
             out_specs=(P(), P()),
             check_vma=False,
         )
-        t0 = time.perf_counter() if tr is not None else 0.0
-        got = fn(
-            q_w, slab.sketches, slab.fills, slab.ids,
-            jnp.asarray(idx.reshape(-1)), jnp.asarray(msk.reshape(-1)),
-        )
-        if tr is not None:
-            tr.add_stage("kernel_score", time.perf_counter() - t0)
-        return got
+        with stage(tr, "kernel_score"):
+            return fn(
+                q_w, slab.sketches, slab.fills, slab.ids,
+                jnp.asarray(idx.reshape(-1)), jnp.asarray(msk.reshape(-1)),
+            )
 
     def _query_placed(
         self,
@@ -1176,13 +1158,11 @@ class SketchEngine:
                 tr.note_width(slab.n_bins)
             slots = None
             if prefilter:
-                t0 = time.perf_counter() if tr is not None else 0.0
-                qkeys = self._query_band_keys(
-                    qs, slab.n_bins, rows, cache, qkeys_cache
-                )
-                slots = self._slab_candidates(slab, qkeys, now, stats, tr=tr)
-                if tr is not None:
-                    tr.add_stage("band_lookup", time.perf_counter() - t0)
+                with stage(tr, "band_lookup"):
+                    qkeys = self._query_band_keys(
+                        qs, slab.n_bins, rows, cache, qkeys_cache
+                    )
+                    slots = self._slab_candidates(slab, qkeys, now, stats, tr=tr)
                 if slots is not None:
                     if len(slots) == 0:
                         continue
@@ -1213,10 +1193,8 @@ class SketchEngine:
                 out_specs=(P(), P()),
                 check_vma=False,
             )
-            t0 = time.perf_counter() if tr is not None else 0.0
-            sc_all, ids_all = fn(q_w, slab.sketches, slab.fills, slab.ids, valid)
-            if tr is not None:
-                tr.add_stage("kernel_score", time.perf_counter() - t0)
+            with stage(tr, "kernel_score"):
+                sc_all, ids_all = fn(q_w, slab.sketches, slab.fills, slab.ids, valid)
             parts_s.append(sc_all)
             parts_i.append(ids_all)
         if hv is not None:  # replicated head: scored once, counted once
@@ -1229,11 +1207,8 @@ class SketchEngine:
             return (jnp.full((qs.shape[0], k), -jnp.inf, jnp.float32),
                     jnp.full((qs.shape[0], k), -1, jnp.int32))
         # always merge: slab partials are (Q, k·D) all-gathers, crop to k
-        t0 = time.perf_counter() if tr is not None else 0.0
-        got = merge_segment_topk(parts_s, parts_i, k)
-        if tr is not None:
-            tr.add_stage("merge", time.perf_counter() - t0)
-        return got
+        with stage(tr, "merge"):
+            return merge_segment_topk(parts_s, parts_i, k)
 
     def _sharded_view_topk(
         self, mesh: Mesh, axis: str, qs: jax.Array, view: SegmentView, k: int,

@@ -78,6 +78,7 @@ and restores from cold without re-sketching anything.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -88,6 +89,7 @@ from .. import faults
 from ..core import binsketch, counting
 from ..core import packed as pk
 from ..obs import metrics as obs_metrics
+from ..obs.trace import span
 from .banding import BandIndex, BandPolicy
 from .store import SegmentView, _grow
 from .supervision import JobSupervisor, SupervisedJob
@@ -95,6 +97,18 @@ from .supervision import JobSupervisor, SupervisedJob
 __all__ = ["DistillPolicy", "SealedSegment", "SegmentedStore"]
 
 _HEAD = -1  # segment index of the mutable head in the location map
+
+
+def _in_span(name: str, **stats):
+    """Run the decorated function inside the span ``repro.<name>``: the
+    background job bodies, whose spans land on the worker's thread."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            with span(name, **stats):
+                return fn(*args, **kwargs)
+        return run
+    return wrap
 
 
 def _check_rows_match(ids: np.ndarray, idx: jax.Array) -> None:
@@ -411,19 +425,23 @@ class _Head:
         self.fills = self.fills.at[rows].set(counting.counter_fills(clamped))
         return sat
 
-    def append(
-        self, counts: jax.Array, ids: np.ndarray, born, exact: bool
-    ) -> range:
-        """``born`` may be a scalar (fresh inserts) or a (B,) array (sealed
-        relocations carrying their original birth time)."""
+    def append(self, counts: jax.Array) -> range:
+        """Write (B, N) ``counts`` into the B rows after the last on the
+        device (counters, packed rows, fills, clamp flags) and return those
+        rows; :meth:`index` then records them on the host."""
         b = int(counts.shape[0])
-        if b == 0:
-            return range(self.size, self.size)
         self.ensure_capacity(self.size + b)
         lo = self.size
         rows = jnp.arange(lo, lo + b)
         sat = self._write_rows(rows, counts.astype(jnp.int32))
         self.sat_dev = self.sat_dev.at[rows].set(sat)
+        return range(lo, lo + b)
+
+    def index(self, rows: range, ids: np.ndarray, born, exact: bool) -> None:
+        """Host metadata of the rows :meth:`append` just wrote. ``born``
+        may be a scalar (fresh inserts) or a (B,) array (sealed relocations
+        carrying their original birth time)."""
+        lo, b = rows.start, len(rows)
         self.ids[lo : lo + b] = ids
         self.valid[lo : lo + b] = True
         self.born[lo : lo + b] = born
@@ -439,7 +457,6 @@ class _Head:
         self.size += b
         self._meta_cache = None
         self._ttl_cache = None
-        return range(lo, lo + b)
 
     def add_counts(self, rows: np.ndarray, deltas: jax.Array) -> None:
         """Saturating ``counters[rows] += deltas`` (unique rows) + refresh.
@@ -524,6 +541,8 @@ class SegmentedStore:
     head_hits: int = 0
     _loc: Dict[int, Tuple[int, int]] = dataclasses.field(default_factory=dict)
     _n_live: int = 0
+    # add calls so far, the ``call`` stat of each ``repro.store.add`` span
+    _adds: int = dataclasses.field(default=0, init=False, repr=False)
     # epochs drive the placement caches (engine/placement.py): the layout
     # epoch bumps when the *set* of sealed segments changes (seal, compact,
     # background swap) and invalidates resident device slabs; the valid
@@ -754,10 +773,11 @@ class SegmentedStore:
         # the occupancy scatter, or insert->retract round-trips on
         # non-deduplicated rows would leave phantom counts (and a wrong
         # binary sketch) behind
-        idx = counting.dedup_padded(idx)
-        if backend is not None:
-            return backend.count(self.cfg, self.mapping, idx)
-        return counting.count_indices_dense(self.cfg, self.mapping, idx)
+        with span("store.count", docs=int(idx.shape[0])):
+            idx = counting.dedup_padded(idx)
+            if backend is not None:
+                return backend.count(self.cfg, self.mapping, idx)
+            return counting.count_indices_dense(self.cfg, self.mapping, idx)
 
     def _insert_counts(
         self,
@@ -773,9 +793,12 @@ class SegmentedStore:
         if ids is None:
             ids = np.arange(self.next_id, self.next_id + b, dtype=np.int64)
             self.next_id += b
-        rows = self.head.append(counts, ids, now, exact)
-        for gid, row in zip(ids, rows):
-            self._loc[int(gid)] = (_HEAD, row)
+        with span("store.head_write", docs=b):
+            rows = self.head.append(counts)
+        with span("store.index", docs=b):
+            self.head.index(rows, ids, now, exact)
+            for gid, row in zip(ids, rows):
+                self._loc[int(gid)] = (_HEAD, row)
         self._n_live += b
         if self.seal_rows is not None and self.head.size >= self.seal_rows:
             self.seal()
@@ -792,10 +815,13 @@ class SegmentedStore:
         """Count-sketch (B, P) padded sparse rows into the head; returns the
         assigned (contiguous, fresh) global doc ids."""
         lo = self.next_id
-        for s in range(0, idx.shape[0], batch):
-            self._insert_counts(
-                self._count_rows(idx[s : s + batch], backend), now=now, exact=True
-            )
+        self._adds += 1
+        with span("store.add", docs=int(idx.shape[0]), call=self._adds):
+            for s in range(0, idx.shape[0], batch):
+                self._insert_counts(
+                    self._count_rows(idx[s : s + batch], backend), now=now,
+                    exact=True,
+                )
         return range(lo, self.next_id)
 
     def add_sketches(self, sketches: jax.Array, *, now: float = 0.0) -> range:
@@ -1065,24 +1091,23 @@ class SegmentedStore:
         h = self.head
         if h.size == 0:
             return None
-        got = _gather_live(self._parts(sealed=False))
-        seg = None
-        if got is not None:
-            sk, fl, ids, born = got
-            seg = SealedSegment(
-                sk, fl, ids, np.ones(len(ids), bool), born,
-                band_index=self._band_index_for(sk, len(ids), backend),
-            )
-            self.sealed.append(seg)
-            seg_i = len(self.sealed) - 1
-            for row, gid in enumerate(seg.ids):
-                self._loc[int(gid)] = (seg_i, row)
-            obs_metrics.inc("lifecycle.seal.runs")
-            obs_metrics.inc("lifecycle.seal.rows", seg.n_rows)
-        cap = h.capacity
-        if self.seal_rows is not None:  # an overshooting batch grew it
-            cap = min(cap, int(self.seal_rows))
-        self.head = _Head.create(self.cfg.n_bins, self.cfg.n_words, cap)
+        with span("store.seal", rows=int(h.valid[: h.size].sum())):
+            got = _gather_live(self._parts(sealed=False))
+            seg = None
+            if got is not None:
+                sk, fl, ids, born = got
+                seg = SealedSegment(
+                    sk, fl, ids, np.ones(len(ids), bool), born,
+                    band_index=self._band_index_for(sk, len(ids), backend),
+                )
+                self.sealed.append(seg)
+                seg_i = len(self.sealed) - 1
+                for row, gid in enumerate(seg.ids):
+                    self._loc[int(gid)] = (seg_i, row)
+            cap = h.capacity
+            if self.seal_rows is not None:  # an overshooting batch grew it
+                cap = min(cap, int(self.seal_rows))
+            self.head = _Head.create(self.cfg.n_bins, self.cfg.n_words, cap)
         self._layout_epoch += 1
         return seg
 
@@ -1109,23 +1134,22 @@ class SegmentedStore:
                 f"expected (B, {self.cfg.n_words}) packed rows at the base "
                 f"width, got {tuple(sketches.shape)}"
             )
-        fills = pk.row_popcount(sketches).astype(jnp.int32)
-        ids = np.arange(self.next_id, self.next_id + b, dtype=np.int64)
-        self.next_id += b
-        seg = SealedSegment(
-            sketches, fills, ids, np.ones(b, bool),
-            np.full(b, float(now), np.float64),
-            band_index=self._band_index_for(sketches, b, backend),
-        )
-        self.sealed.append(seg)
-        seg_i = len(self.sealed) - 1
-        self._loc.update(
-            zip(ids.tolist(), ((seg_i, row) for row in range(b)))
-        )
+        with span("store.seal", rows=b):
+            fills = pk.row_popcount(sketches).astype(jnp.int32)
+            ids = np.arange(self.next_id, self.next_id + b, dtype=np.int64)
+            self.next_id += b
+            seg = SealedSegment(
+                sketches, fills, ids, np.ones(b, bool),
+                np.full(b, float(now), np.float64),
+                band_index=self._band_index_for(sketches, b, backend),
+            )
+            self.sealed.append(seg)
+            seg_i = len(self.sealed) - 1
+            self._loc.update(
+                zip(ids.tolist(), ((seg_i, row) for row in range(b)))
+            )
         self._n_live += b
         self._layout_epoch += 1
-        obs_metrics.inc("lifecycle.seal.runs")
-        obs_metrics.inc("lifecycle.seal.rows", b)
         return range(int(ids[0]), int(ids[-1]) + 1)
 
     def _widths_present(self) -> List[Optional[int]]:
@@ -1153,29 +1177,28 @@ class SegmentedStore:
         if not self.sealed:
             return stats
         new_sealed: List[SealedSegment] = []
-        for width in self._widths_present():
-            stats["groups"] += 1
-            parts = [
-                (seg.sketches, seg.fills, seg.ids, seg.valid, seg.born)
-                for seg in self.sealed if seg.n_bins == width
-            ]
-            got = _gather_live(parts)
-            if got is None:
-                continue
-            sk, fl, ids, born = got
-            new_sealed.append(SealedSegment(
-                sk, fl, ids, np.ones(len(ids), bool), born, n_bins=width,
-                band_index=self._band_index_for(sk, len(ids)),
-            ))
-        self._layout_epoch += 1
-        self.sealed = new_sealed
-        for seg_i, seg in enumerate(self.sealed):
-            for row, gid in enumerate(seg.ids):
-                self._loc[int(gid)] = (seg_i, row)
-            stats["rows_out"] += seg.n_rows
-        obs_metrics.inc("lifecycle.compact.runs")
-        obs_metrics.inc("lifecycle.compact.rows_in", stats["rows_in"])
-        obs_metrics.inc("lifecycle.compact.rows_out", stats["rows_out"])
+        with span("store.compact", rows_in=stats["rows_in"],
+                  rows_out=sum(s.n_live for s in self.sealed)):
+            for width in self._widths_present():
+                stats["groups"] += 1
+                parts = [
+                    (seg.sketches, seg.fills, seg.ids, seg.valid, seg.born)
+                    for seg in self.sealed if seg.n_bins == width
+                ]
+                got = _gather_live(parts)
+                if got is None:
+                    continue
+                sk, fl, ids, born = got
+                new_sealed.append(SealedSegment(
+                    sk, fl, ids, np.ones(len(ids), bool), born, n_bins=width,
+                    band_index=self._band_index_for(sk, len(ids)),
+                ))
+            self._layout_epoch += 1
+            self.sealed = new_sealed
+            for seg_i, seg in enumerate(self.sealed):
+                for row, gid in enumerate(seg.ids):
+                    self._loc[int(gid)] = (seg_i, row)
+                stats["rows_out"] += seg.n_rows
         return stats
 
     # ------------------------------------------------- background compaction
@@ -1260,7 +1283,10 @@ class SegmentedStore:
 
         band_policy = self.band_policy
         sup = self.supervisor
+        rows_in = sum(len(p[2]) for _, parts, _ in snap for p in parts)
+        rows_out = sum(int(p[3].sum()) for _, parts, _ in snap for p in parts)
 
+        @_in_span("store.compact", rows_in=rows_in, rows_out=rows_out)
         def work():
             faults.inject("compact.work")
             out = []
@@ -1379,7 +1405,10 @@ class SegmentedStore:
 
         band_policy = self.band_policy
         sup = self.supervisor
+        rows_in = sum(len(x[4]) for x in snap)
+        rows_out = sum(int(x[5].sum()) for x in snap)
 
+        @_in_span("store.distill", rows_in=rows_in, rows_out=rows_out)
         def work():
             faults.inject("distill.work")
             out = []
@@ -1559,12 +1588,6 @@ class SegmentedStore:
             )
         self._layout_epoch += 1
         self._valid_epoch += 1
-        # background swaps carry their op ("compact" | "distill") on the
-        # supervised job — the throughput counters split on it
-        op = job.job.op
-        obs_metrics.inc(f"lifecycle.{op}.runs")
-        obs_metrics.inc(f"lifecycle.{op}.rows_in", stats["rows_in"])
-        obs_metrics.inc(f"lifecycle.{op}.rows_out", stats["rows_out"])
         return stats
 
     def expire(self, ttl: float, now: float) -> int:

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core import binsketch, packed as pk
+from ..obs.trace import span
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
     from .backends import Backend
@@ -64,6 +65,8 @@ class SketchStore:
     _sketches: jax.Array  # (capacity, W) uint32; rows >= size are zero
     _fills: jax.Array  # (capacity,) int32; rows >= size are zero
     size: int = 0
+    # add calls so far, the ``call`` stat of each ``repro.store.add`` span
+    _adds: int = dataclasses.field(default=0, init=False, repr=False)
 
     # ------------------------------------------------------------ construct
     @classmethod
@@ -160,8 +163,10 @@ class SketchStore:
         device memory during a large ingest is one batch, not the whole
         corpus twice."""
         lo = self.size
-        for s in range(0, idx.shape[0], batch):
-            self.add_sketches(self._sketch_rows(idx[s : s + batch], backend))
+        self._adds += 1
+        with span("store.add", docs=int(idx.shape[0]), call=self._adds):
+            for s in range(0, idx.shape[0], batch):
+                self.add_sketches(self._sketch_rows(idx[s : s + batch], backend))
         return range(lo, self.size)
 
     def add_sketches(self, sketches: jax.Array) -> range:

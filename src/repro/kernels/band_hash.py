@@ -62,5 +62,6 @@ def band_hash_kernel(
         in_specs=[pl.BlockSpec((wpb, nb_eff, block_rows), lambda i: (0, 0, i))],
         out_specs=pl.BlockSpec((nb_eff, block_rows), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((nb_eff, bsz), jnp.uint32),
+        name="band_hash",
         interpret=interpret,
     )(src)

@@ -68,5 +68,6 @@ def count_bins_kernel(
         in_specs=[pl.BlockSpec((block_rows, bins.shape[1]), lambda i, j: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, tile_bins), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, n_bins), jnp.int32),
+        name="count_update",
         interpret=interpret,
     )(bins)

@@ -64,5 +64,6 @@ def hash_build_kernel(
         ],
         out_specs=pl.BlockSpec((block_rows, tile_words), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, n_words), jnp.int32),
+        name="hash_build",
         interpret=interpret,
     )(coeffs, idx_t)

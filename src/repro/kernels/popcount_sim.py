@@ -139,5 +139,6 @@ def sketch_score_kernel(
         ],
         out_specs=pl.BlockSpec((block_q, block_c), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((q, c), jnp.float32),
+        name="sketch_score",
         interpret=interpret,
     )(a, b, na, nb)

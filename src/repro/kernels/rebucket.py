@@ -88,5 +88,6 @@ def rebucket_kernel(
         in_specs=[pl.BlockSpec((block_rows, w_pad), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, w_new), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, w_new), jnp.uint32),
+        name="rebucket",
         interpret=interpret,
     )(src)

@@ -82,5 +82,6 @@ def build_sketch_kernel(
         in_specs=[pl.BlockSpec((p, block_rows), lambda i, j: (0, i))],
         out_specs=pl.BlockSpec((block_rows, tile_words), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, n_words), jnp.int32),
+        name="sketch_build",
         interpret=interpret,
     )(bins_t)

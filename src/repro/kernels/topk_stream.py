@@ -189,5 +189,6 @@ def sketch_topk_kernel(
             jax.ShapeDtypeStruct((q, block_c), jnp.float32),
             jax.ShapeDtypeStruct((q, block_c), jnp.int32),
         ],
+        name="topk_stream",
         interpret=interpret,
     )(a, b, na, nb, valid)

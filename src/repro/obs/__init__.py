@@ -1,6 +1,6 @@
 """repro.obs — the telemetry plane (DESIGN.md §14).
 
-Dependency-free observability for the sketch engine:
+Observability for the sketch engine:
 
 ========================  ==================================================
 module                    what it holds
@@ -8,8 +8,9 @@ module                    what it holds
 :mod:`repro.obs.clock`    one injectable time source (`Clock`, `ManualClock`)
                           shared by supervision, TTL, and metrics
 :mod:`repro.obs.metrics`  `MetricsRegistry`: counters / gauges / log-bucketed
-                          histograms, JSON snapshot, Prometheus text
-:mod:`repro.obs.trace`    sampled per-query `QueryTrace` (stage wall time,
+                          histograms, JSON snapshot
+:mod:`repro.obs.trace`    ``repro.*`` profiler spans (`span`, `stage`);
+                          sampled per-query `QueryTrace` (stage host time,
                           candidate fractions, widths, degraded hits)
 :mod:`repro.obs.probe`    `RecallProbe`: online recall vs exact ground truth
                           on a supervised background job; `exact_topk`

@@ -125,8 +125,7 @@ class MetricsRegistry:
     """Named counters + gauges + histograms behind one lock.
 
     Names are dotted strings (``"query.stage.kernel_score_s"``); the
-    snapshot keeps them verbatim, the Prometheus formatter rewrites
-    them to ``repro_query_stage_kernel_score_s``.
+    snapshot keeps them verbatim.
     """
 
     def __init__(self, clock: Optional[Callable[[], float]] = None,
@@ -179,34 +178,6 @@ class MetricsRegistry:
                 "histograms": {k: h.snapshot()
                                for k, h in sorted(self._hists.items())},
             }
-
-    def to_prometheus(self, prefix: str = "repro") -> str:
-        """Text exposition format (one sample per line, quantiles as
-        summary labels) — what a scrape endpoint would serve."""
-        snap = self.snapshot()
-        out = []
-
-        def _name(raw: str) -> str:
-            return prefix + "_" + "".join(
-                c if (c.isalnum() or c == "_") else "_" for c in raw)
-
-        for k in sorted(snap["counters"]):
-            n = _name(k)
-            out.append(f"# TYPE {n} counter")
-            out.append(f"{n} {snap['counters'][k]}")
-        for k in sorted(snap["gauges"]):
-            n = _name(k)
-            out.append(f"# TYPE {n} gauge")
-            out.append(f"{n} {snap['gauges'][k]}")
-        for k, h in snap["histograms"].items():
-            n = _name(k)
-            out.append(f"# TYPE {n} summary")
-            for q in ("0.5", "0.9", "0.99"):
-                p = h[{"0.5": "p50", "0.9": "p90", "0.99": "p99"}[q]]
-                out.append(f'{n}{{quantile="{q}"}} {p}')
-            out.append(f"{n}_sum {h['sum']}")
-            out.append(f"{n}_count {h['count']}")
-        return "\n".join(out) + "\n"
 
 
 # --------------------------------------------------------------------------

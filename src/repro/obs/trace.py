@@ -1,28 +1,34 @@
-"""Sampled per-query traces (DESIGN.md §14).
+"""Profiler spans and sampled per-query traces (DESIGN.md §14).
 
-A `QueryTrace` is one query call's worth of structure: per-stage wall
-time (``rebucket`` -> ``band_lookup`` -> ``candidate_gather`` ->
+`span` opens a ``repro.<name>`` annotation on the profiler's host timeline
+-- the clock the device trace uses -- at each layer boundary of the engine
+and the store (``repro.engine.query``, ``repro.store.add``, ...). With no
+profiler running a span is one enter and exit, about a microsecond. Its
+stats are host values already at hand (rows, docs, k); never a device
+value, whose read would block the dispatch stream being measured.
+
+A `QueryTrace` is one query call's worth of structure: per-stage host
+seconds (``rebucket`` -> ``band_lookup`` -> ``candidate_gather`` ->
 ``kernel_score`` -> ``merge``), the candidate fraction each segment
-contributed, the sketch widths touched, which degraded modes fired,
-and whether ``k`` overflowed the live corpus. The engine threads the
-trace object through its query internals; every instrumentation site
-is guarded by ``tr is not None`` so the disarmed path pays a single
-module-global None-check per query (same contract as `metrics`).
+contributed, the sketch widths touched, which degraded modes fired, and
+whether ``k`` overflowed the live corpus. Each stage site is one
+``with stage(tr, name)``: a ``repro.query.<name>`` span, and the host
+seconds added to the trace when one is armed (``tr`` not None).
 
-Timing caveat: stages are *host* wall time around dispatch. jax
-dispatch is async, so a stage that merely enqueues device work reads
-near-zero while the stage that first blocks on the result (the final
-merge's ``device_get``, or the caller's) absorbs the device time. The
-totals are still the right signal — they are what the serving thread
-actually waits on — but per-stage splits on an accelerator reflect
-dispatch+sync points, not kernel occupancy.
+Reading the two: ``stages_s`` is dispatch time. jax dispatch is async, so
+a stage that merely enqueues device work reads near zero there. What a
+stage costs the device comes from a profile: each device program is
+charged to the spans open on the host thread that launched it (the
+profile's flow stats, or on the CPU its ``run_id``, link the program to
+its launch call), so the union of the device operations of the programs
+launched under ``repro.query.kernel_score`` is that stage's device time.
 
 The collector keeps the last ``capacity`` traces in a ring and, when a
 `MetricsRegistry` is attached, folds every finished trace into it:
-``query.stage.<stage>_s`` histograms, ``query.candidate_frac``,
-per-width touch counters, and ``query.k_overflow``. (``query.calls`` /
-``query.rows`` counters come from the engine itself so they stay exact
-under sampling.)
+``query.stage.<stage>_s`` histograms, ``query.candidate_frac`` and
+``query.degraded.<component>`` counters. (``query.calls`` /
+``query.rows`` / ``query.k_overflow`` counters come from the engine itself
+so they stay exact under sampling.)
 """
 
 from __future__ import annotations
@@ -32,6 +38,8 @@ import threading
 import time
 from collections import deque
 from typing import Callable, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from . import metrics as _metrics
 from .clock import Clock, ensure_clock
@@ -45,6 +53,8 @@ __all__ = [
     "finish",
     "install",
     "scoped",
+    "span",
+    "stage",
     "start",
 ]
 
@@ -53,6 +63,27 @@ __all__ = [
 #: multi-segment query exercises all five.
 STAGES = ("rebucket", "band_lookup", "candidate_gather", "kernel_score",
           "merge")
+
+#: Prefix of every span the program opens.
+SPAN_PREFIX = "repro."
+
+
+def span(name: str, **stats) -> TraceAnnotation:
+    """A ``repro.<name>`` profiler span with host-value ``stats``."""
+    return TraceAnnotation(SPAN_PREFIX + name, **stats)
+
+
+@contextlib.contextmanager
+def stage(tr: Optional["QueryTrace"], name: str) -> Iterator[None]:
+    """Query stage ``name``: a ``repro.query.<name>`` span, plus its host
+    seconds in ``tr`` when a sampled trace is armed."""
+    with span("query." + name):
+        if tr is None:
+            yield
+            return
+        t0 = time.perf_counter()
+        yield
+        tr.add_stage(name, time.perf_counter() - t0)
 
 
 class QueryTrace:
@@ -161,8 +192,6 @@ class TraceCollector:
         cf = tr.candidate_frac
         if cf is not None:
             reg.observe("query.candidate_frac", cf)
-        for w in tr.widths:
-            reg.inc(f"query.width.{w}")
         for component in tr.degraded:
             reg.inc(f"query.degraded.{component}")
         # query.k_overflow is engine-side too, same exactness argument

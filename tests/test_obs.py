@@ -100,11 +100,6 @@ def test_registry_snapshot_json_round_trip_and_prometheus():
     assert snap["gauges"]["probe.recall"] == 0.625
     hist = snap["histograms"]["query.stage.kernel_score_s"]
     assert hist["count"] == 3 and hist["min"] == 0.001
-    text = reg.to_prometheus()
-    assert "# TYPE repro_query_calls counter" in text
-    assert "repro_query_calls 3" in text
-    assert 'repro_query_stage_kernel_score_s{quantile="0.99"}' in text
-    assert "repro_probe_recall 0.625" in text
 
 
 def test_free_helpers_are_noops_disarmed_and_land_when_armed():
@@ -324,3 +319,101 @@ def test_enable_disable_idempotent_and_scoped():
     obs.disable()
     assert obs_metrics.active() is None and obs_trace.active() is None
     obs.disable()  # idempotent
+
+
+# ------------------------------------------------------ profiler spans
+def _program_spans(log_dir):
+    """``repro.*`` host events of the one profile under ``log_dir``:
+    (name, start_ns, end_ns, thread line, {stat: value})."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line_i, ln in enumerate(plane.lines):
+            for e in ln.events:
+                if e.name.startswith(obs_trace.SPAN_PREFIX):
+                    out.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                                (plane.name, line_i), {k: v for k, v in e.stats}))
+    return out
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """One profile: a segmented engine (two sealed segments, a head of 12)
+    answers an armed query of 6 rows, then takes an insert of 12 docs that
+    fills the head to its 24 and seals it."""
+    cfg, mapping, idx = _fixture()
+    eng = SketchEngine.build(cfg, mapping, backend="oracle", mutable=True,
+                             seal_rows=24)
+    for lo in (0, 24, 48):
+        eng.add(jnp.asarray(idx[lo : min(lo + 24, 60)]))
+    q, new = jnp.asarray(idx[60:66]), jnp.asarray(idx[66:78])
+    eng.query(q, 5)  # compile outside the profile
+    log_dir = str(tmp_path_factory.mktemp("profile"))
+    eng.enable_metrics()
+    try:
+        jax.profiler.start_trace(log_dir)
+        try:
+            s, _ = eng.query(q, 5)
+            s.block_until_ready()
+            eng.add(new)
+            jax.block_until_ready(eng.store.sealed[-1].sketches)
+        finally:
+            jax.profiler.stop_trace()
+        last = obs_trace.active().last()
+    finally:
+        obs.disable()
+    return _program_spans(log_dir), last, len(eng.store.sealed)
+
+
+def _one(spans, name):
+    got = [s for s in spans if s[0] == name]
+    assert len(got) == 1, (name, [s[0] for s in spans])
+    return got[0]
+
+
+@pytest.mark.parametrize("outer,inner", [
+    ("repro.engine.query", "repro.query.rebucket"),
+    ("repro.engine.query", "repro.query.kernel_score"),
+    ("repro.engine.query", "repro.query.merge"),
+    ("repro.store.add", "repro.store.count"),
+    ("repro.store.add", "repro.store.head_write"),
+    ("repro.store.add", "repro.store.index"),
+    ("repro.store.add", "repro.store.seal"),
+])
+def test_program_spans_nest_on_the_calling_thread(profiled, outer, inner):
+    spans, _, _ = profiled
+    o = _one(spans, outer)
+    inside = [s for s in spans if s[0] == inner]
+    assert inside, f"no {inner} span in the profile"
+    for s in inside:
+        assert s[3] == o[3]  # the same host thread
+        assert o[1] <= s[1] and s[2] <= o[2], f"{inner} outside {outer}"
+
+
+@pytest.mark.parametrize("name,stats", [
+    ("repro.engine.query", {"rows": 6, "k": 5, "path": "query", "call": 2}),
+    ("repro.store.add", {"docs": 12, "call": 4}),
+    ("repro.store.count", {"docs": 12}),
+    ("repro.store.head_write", {"docs": 12}),
+    ("repro.store.index", {"docs": 12}),
+    ("repro.store.seal", {"rows": 24}),
+])
+def test_program_spans_carry_the_request_sizes(profiled, name, stats):
+    spans, _, n_sealed = profiled
+    got = _one(spans, name)[4]
+    assert {k: got[k] for k in stats} == stats
+    assert n_sealed == 3  # the insert sealed: 60 + 12 docs in 24-row segments
+
+
+def test_armed_trace_stages_are_the_query_span_names(profiled):
+    spans, last, _ = profiled
+    prefix = obs_trace.SPAN_PREFIX + "query."
+    named = {s[0][len(prefix):] for s in spans if s[0].startswith(prefix)}
+    assert set(last["stages_s"]) == named
+    assert named <= set(obs_trace.STAGES)
