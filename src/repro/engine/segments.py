@@ -120,6 +120,37 @@ def _check_rows_match(ids: np.ndarray, idx: jax.Array) -> None:
         )
 
 
+def _clamp_rows(counts: jax.Array, counter_max: int):
+    """(B, N) int32 occupancy -> the head's four per-row results: the u16
+    counters clamped at ``counter_max``, their packed rows, their fills, and
+    whether the clamp lost information (any bin above ``counter_max``).
+    Pure math, shared by the append and the row-rewrite paths."""
+    sat = jnp.any(counts > counter_max, axis=-1)
+    clamped = jnp.clip(counts, 0, counter_max).astype(
+        counting.COUNTER_DTYPE
+    )
+    return (clamped, counting.counters_to_packed(clamped),
+            counting.counter_fills(clamped), sat)
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3),
+                   static_argnames="counter_max")
+def _append_rows(counters, packed, fills, sat_dev, counts, lo, counter_max):
+    """Write (B, N) ``counts`` into rows ``[lo, lo + B)`` of the head's
+    device buffers as one program: one contiguous slice update per buffer,
+    in place (the buffers are donated). ``lo`` is traced, so one compile
+    serves every offset. ``dynamic_update_slice`` moves a start that would
+    overrun back to fit, so the caller must guarantee ``lo + B <= cap``.
+    ``counter_max`` is ``counting.COUNTER_MAX``, passed at each call: a
+    module global read while tracing would stay baked into the cached
+    program after it changes."""
+    rows = _clamp_rows(counts.astype(jnp.int32), counter_max)
+    return tuple(
+        jax.lax.dynamic_update_slice_in_dim(buf, new, lo, axis=0)
+        for buf, new in zip((counters, packed, fills, sat_dev), rows)
+    )
+
+
 def _grow_host(arr: np.ndarray, new_capacity: int) -> np.ndarray:
     out = np.zeros((new_capacity,) + arr.shape[1:], arr.dtype)
     out[: arr.shape[0]] = arr
@@ -347,6 +378,19 @@ class _Head:
     is refused on such rows rather than corrupting the sketch (flags stay
     on device so the test never stalls the ingest dispatch stream; see the
     field comment).
+
+    Two write paths: :meth:`append` writes a contiguous block of new rows
+    with one jitted in-place slice update (``_append_rows``), and
+    :meth:`_write_rows` rewrites arbitrary rows with scatters (update,
+    retract, delete). The append *donates* ``counters``, ``packed``,
+    ``fills`` and ``sat_dev``: code that holds one of those arrays itself,
+    rather than a slice of it, must not expect it to survive an append.
+    A slice is a copy, except that ``x[:n]`` with ``n == x.shape[0]`` is
+    ``x`` itself, so the views of a full head (``size == capacity``) hold
+    the head's own buffers. That is safe because an append to a full head
+    grows it first (:meth:`ensure_capacity` allocates new buffers), and
+    ``SegmentedStore._insert_counts`` never appends an empty batch: a
+    buffer a view holds is never donated.
     """
 
     counters: jax.Array  # (cap, N) uint16
@@ -416,25 +460,33 @@ class _Head:
         per-row *device* flag of whether the clamp lost information (any
         bin above ``COUNTER_MAX``) — the caller folds it into ``sat_dev``;
         nothing here blocks the async dispatch stream."""
-        sat = jnp.any(counts > counting.COUNTER_MAX, axis=-1)
-        clamped = jnp.clip(counts, 0, counting.COUNTER_MAX).astype(
-            counting.COUNTER_DTYPE
-        )
+        clamped, packed, fills, sat = _clamp_rows(counts, counting.COUNTER_MAX)
         self.counters = self.counters.at[rows].set(clamped)
-        self.packed = self.packed.at[rows].set(counting.counters_to_packed(clamped))
-        self.fills = self.fills.at[rows].set(counting.counter_fills(clamped))
+        self.packed = self.packed.at[rows].set(packed)
+        self.fills = self.fills.at[rows].set(fills)
+        obs_metrics.inc("store.head.rows_rewritten", int(rows.shape[0]))
         return sat
 
     def append(self, counts: jax.Array) -> range:
         """Write (B, N) ``counts`` into the B rows after the last on the
         device (counters, packed rows, fills, clamp flags) and return those
-        rows; :meth:`index` then records them on the host."""
+        rows; :meth:`index` then records them on the host. One program,
+        in place: see the class docstring for what the donation asks of
+        code that holds the head's arrays."""
         b = int(counts.shape[0])
-        self.ensure_capacity(self.size + b)
         lo = self.size
-        rows = jnp.arange(lo, lo + b)
-        sat = self._write_rows(rows, counts.astype(jnp.int32))
-        self.sat_dev = self.sat_dev.at[rows].set(sat)
+        self.ensure_capacity(lo + b)
+        if lo + b > self.capacity:
+            # the slice update would shift the block back over acknowledged rows
+            raise RuntimeError(
+                f"head append of {b} rows at row {lo} overruns capacity "
+                f"{self.capacity}"
+            )
+        self.counters, self.packed, self.fills, self.sat_dev = _append_rows(
+            self.counters, self.packed, self.fills, self.sat_dev, counts,
+            np.int32(lo), counter_max=counting.COUNTER_MAX,
+        )
+        obs_metrics.inc("store.head.rows_appended", b)
         return range(lo, lo + b)
 
     def index(self, rows: range, ids: np.ndarray, born, exact: bool) -> None:

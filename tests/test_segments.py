@@ -202,6 +202,155 @@ def test_head_capacity_capped_at_seal_rows():
                   backends=("oracle", "pallas-interpret"))
 
 
+# -------------------------------------------------- the head's append path
+def _head_arrays(head):
+    return [np.asarray(a) for a in (head.counters, head.packed, head.fills,
+                                    head.sat_dev)]
+
+
+def _random_counts(rng, b, n_bins, clamp):
+    counts = rng.integers(0, 3, (b, n_bins)).astype(np.int32)
+    if clamp:  # some rows hold a bin above COUNTER_MAX: the clamp flag is set
+        counts[rng.random(b) < 0.5, rng.integers(0, n_bins)] = (
+            counting.COUNTER_MAX + 7)
+    return jnp.asarray(counts)
+
+
+@pytest.mark.parametrize("capacity,batches,clamp", [
+    (64, [8, 8, 16, 4], True),  # several offsets, clamped rows among them
+    (64, [5, 11, 1, 30], False),  # unaligned offsets
+    (8, [5, 6, 20], True),  # appends that grow the capacity, twice
+    (16, [8, 8], False),  # fills the head to capacity exactly
+])
+def test_head_append_matches_scatter_path(capacity, batches, clamp):
+    """The in-place append writes bit-for-bit what the scatter path
+    (``set_counts`` on the same rows) writes: counters, packed rows, fills
+    and clamp flags, at every offset and across capacity growth."""
+    from repro.engine.segments import _Head
+
+    n_bins = 300  # packs to 10 words, the last one partial
+    rng = np.random.default_rng(sum(batches) + capacity)
+    fast = _Head.create(n_bins, packed.num_words(n_bins), capacity)
+    slow = _Head.create(n_bins, packed.num_words(n_bins), capacity)
+    for b in batches:
+        counts = _random_counts(rng, b, n_bins, clamp)
+        rows = fast.append(counts)
+        assert rows == range(slow.size, slow.size + b)
+        slow.ensure_capacity(slow.size + b)
+        slow.set_counts(np.arange(rows.start, rows.stop), counts)
+        fast.size = slow.size = rows.stop
+        assert fast.capacity == slow.capacity
+        for got, want in zip(_head_arrays(fast), _head_arrays(slow)):
+            np.testing.assert_array_equal(got, want)
+    assert fast.size == sum(batches)
+    assert fast.saturated.any() == clamp
+
+
+def test_head_append_fills_to_seal_rows_like_a_fresh_build():
+    """Appends that fill the head to ``seal_rows`` exactly seal the very
+    rows, bit for bit, that the append-only store builds."""
+    cfg, mapping, idx = _fixture()
+    plain = SketchStore.from_indices(cfg, mapping, jnp.asarray(idx[:32]))
+    store = SegmentedStore.create(cfg, mapping, capacity=16, seal_rows=16)
+    for lo in range(0, 32, 8):
+        store.add(jnp.asarray(idx[lo : lo + 8]))
+    assert len(store.sealed) == 2 and store.head.size == 0
+    for seg, lo in zip(store.sealed, (0, 16)):
+        np.testing.assert_array_equal(np.asarray(seg.sketches),
+                                      np.asarray(plain.sketches[lo : lo + 16]))
+        np.testing.assert_array_equal(np.asarray(seg.fills),
+                                      np.asarray(plain.fills[lo : lo + 16]))
+
+
+def test_head_append_compiles_once_across_offsets():
+    """``lo`` is traced: appends of one shape at new offsets reuse the one
+    compiled program."""
+    from repro.engine import segments
+
+    n_bins = 200
+    head = segments._Head.create(n_bins, packed.num_words(n_bins), 40)
+    counts = jnp.ones((4, n_bins), jnp.int32)
+    head.append(counts)
+    head.size += 4
+    compiled = segments._append_rows._cache_size()
+    for _ in range(9):
+        head.append(counts)
+        head.size += 4
+    assert segments._append_rows._cache_size() == compiled
+    assert np.asarray(head.fills[:40]).tolist() == [n_bins] * 40
+
+
+def _snapshot(store):
+    """Host copies of every device array a head view, ``_parts()`` and a
+    checkpoint tree hold, with the live device arrays themselves."""
+    tree, _ = store.checkpoint_tree()
+    hv = store.head_view()
+    held = [hv.sketches, hv.fills, *store._parts()[-1][:2],
+            *(tree["head"][k] for k in ("counters", "packed", "fills",
+                                        "saturated"))]
+    return held, [np.asarray(a) for a in held]
+
+
+@pytest.mark.parametrize("capacity,first,then,seal_rows", [
+    (8, 8, [4, 4], None),  # size == capacity: the views hold the buffers
+    (16, 8, [4, 2], None),  # size < capacity: the views are copies
+    (16, 8, [8, 4], 16),  # the next append reaches seal_rows and seals
+])
+def test_head_views_survive_later_appends(capacity, first, then, seal_rows):
+    """A head view, ``_parts()`` and a checkpoint tree taken before further
+    appends stay readable and unchanged after them, though the append
+    donates the head's buffers."""
+    cfg, mapping, idx = _fixture()
+    store = SegmentedStore.create(cfg, mapping, capacity=capacity,
+                                  seal_rows=seal_rows)
+    store.add(jnp.asarray(idx[:first]))
+    assert (store.head.size == store.head.capacity) == (first == capacity)
+    held, want = _snapshot(store)
+    own = store.head.packed  # the buffer itself, not a slice: donated
+    lo = first
+    for b in then:
+        store.add(jnp.asarray(idx[lo : lo + b]))
+        lo += b
+    for arr, host in zip(held, want):
+        assert not arr.is_deleted()
+        np.testing.assert_array_equal(np.asarray(arr), host)
+    if first < capacity:  # proves the donation happened, so this test bites
+        assert own.is_deleted()
+    np.testing.assert_array_equal(
+        np.asarray(store.sketches),
+        np.asarray(SketchStore.from_indices(cfg, mapping,
+                                            jnp.asarray(idx[:lo])).sketches))
+
+
+def test_head_write_counters():
+    """``store.head.rows_appended`` counts the rows each add / add_sketches
+    / relocation appends; ``store.head.rows_rewritten`` the rows update,
+    retract and delete rewrite in place."""
+    from repro.obs import metrics as obs_metrics
+
+    cfg, mapping, idx = _fixture()
+    rows = obs_metrics.MetricsRegistry()
+    with obs_metrics.scoped(rows):
+        store = SegmentedStore.create(cfg, mapping, capacity=8)
+        store.add(jnp.asarray(idx[:12]), batch=5)  # batches of 5, 5, 2
+        assert rows.counter("store.head.rows_appended") == 12
+        store.add_sketches(
+            sketch_indices(cfg, mapping, jnp.asarray(idx[12:15])))
+        assert rows.counter("store.head.rows_appended") == 15
+        assert rows.counter("store.head.rows_rewritten") == 0
+        store.update([1, 2], jnp.asarray(idx[20:22]))  # head rows: rewritten
+        assert rows.counter("store.head.rows_rewritten") == 2
+        store.retract_rows([3], jnp.asarray(idx[3:4]))
+        assert rows.counter("store.head.rows_rewritten") == 3
+        store.delete([4, 5, 6])
+        assert rows.counter("store.head.rows_rewritten") == 6
+        store.seal()
+        store.update([7], jnp.asarray(idx[23:24]))  # sealed: relocates
+        store.delete([8])  # sealed: a tombstone, no row write
+        assert rows.counter("store.head.rows_appended") == 16
+        assert rows.counter("store.head.rows_rewritten") == 6
+
+
 def test_add_sketches_and_merge_by_id():
     cfg, mapping, idx = _fixture()
     base = SketchStore.from_indices(cfg, mapping, jnp.asarray(idx[:8]))
