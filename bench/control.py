@@ -22,8 +22,10 @@ a store gets its control from its ``store`` key:
 
 ``FAULTS`` are the faults the check must catch in any cell, planted in the
 system under test: an answer altered where it is produced, half of a query
-batch left unanswered, and an insert acknowledged with the store left
-unchanged.
+batch left unanswered, an insert acknowledged with the store left
+unchanged, an update acknowledged with the store left unchanged, an update
+that adds the new contents and leaves the old row live, and queries
+answered from the contents before the updates acknowledged so far.
 """
 
 from __future__ import annotations
@@ -51,6 +53,7 @@ class _Wrap:
     PHASES = real.PHASES
     query = staticmethod(real.query)
     insert = staticmethod(real.insert)
+    update = staticmethod(real.update)
     views = staticmethod(real.views)
 
 
@@ -126,8 +129,48 @@ class UnchangedState(_Wrap):
         return lo, lo + len(idx), False
 
 
+class UnchangedUpdate(_Wrap):
+    """Updates acknowledged, the store left as it was."""
+
+    @staticmethod
+    def update(eng, ids, idx):
+        return False
+
+
+class UntombstonedUpdate(_Wrap):
+    """Updates that store the new contents under the old ids and leave the
+    old rows live."""
+
+    @staticmethod
+    def update(eng, ids, idx):
+        import jax.numpy as jnp
+
+        store = eng.store
+        n_sealed = len(store.sealed)
+        counts = store._count_rows(jnp.asarray(idx), eng.backend)
+        store._insert_counts(counts, ids=np.asarray(ids, np.int64), now=0.0, exact=True)
+        return len(store.sealed) > n_sealed
+
+
+class StaleReads(_Wrap):
+    """Updates acknowledged but held back until the store is checked, so
+    that every query is answered from the contents before them."""
+
+    @staticmethod
+    def update(eng, ids, idx):
+        eng.__dict__.setdefault("_held", []).append((ids, idx))
+        return False
+
+    @staticmethod
+    def views(eng):
+        for ids, idx in eng.__dict__.pop("_held", []):
+            real.update(eng, ids, idx)
+        return real.views(eng)
+
+
 FAULTS = {"altered_answer": AlteredAnswer, "half_batch": HalfBatch,
-          "unchanged_state": UnchangedState}
+          "unchanged_state": UnchangedState, "unchanged_update": UnchangedUpdate,
+          "untombstoned_update": UntombstonedUpdate, "stale_reads": StaleReads}
 
 
 def main(argv=None) -> int:

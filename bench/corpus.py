@@ -27,8 +27,12 @@ import math
 import numpy as np
 
 #: sub-streams of one run's seed, so that the corpus, the mapping, the
-#: traffic and the check's sample never share random numbers
+#: traffic and the check's sample never share random numbers; a new draw
+#: takes a new stream at the end, so that no earlier draw moves
 STREAM_CORPUS, STREAM_MAPPING, STREAM_POOL, STREAM_TRAFFIC, STREAM_CHECK = range(5)
+#: a mix's choice of op per request, its Zipf keys, and the check's draws
+#: among updated ids
+STREAM_OPS, STREAM_KEYS, STREAM_UPDATE_CHECK = range(5, 8)
 
 
 def rng_for(seed: int, stream: int) -> np.random.Generator:

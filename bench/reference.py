@@ -122,13 +122,15 @@ def _scan_fns(universe: int, n_bins: int, kind: str, kc: int, dtype: str):
         return inter / jnp.maximum(qs + cs - inter, 1.0)
 
     @jax.jit
-    def step(q_hot, q_size, limit, rows, ids, pi, best):
+    def step(q_hot, q_size, limit, ver, rows, ids, born, dead, pi, best):
         c_hot, c_size = onehot(rows, pi)
         inter = jax.lax.dot_general(
             q_hot, c_hot, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         s = score(q_size[:, None], c_size[None, :], inter)
-        s = jnp.where((ids[None, :] >= 0) & (ids[None, :] < limit[:, None]), s, -jnp.inf)
+        seen = ((ids[None, :] >= 0) & (ids[None, :] < limit[:, None])
+                & (born[None, :] <= ver[:, None]) & (ver[:, None] < dead[None, :]))
+        s = jnp.where(seen, s, -jnp.inf)
         nq, nc = inter.shape
         cand = (jnp.concatenate([best[0], s], 1),
                 jnp.concatenate([best[1], jnp.broadcast_to(ids, (nq, nc))], 1),
@@ -136,42 +138,57 @@ def _scan_fns(universe: int, n_bins: int, kind: str, kc: int, dtype: str):
                 jnp.concatenate([best[3], jnp.broadcast_to(
                     c_size.astype(jnp.int32), (nq, nc))], 1))
         # chunks come in ascending id order and ``best`` holds only lower
-        # ids, so top_k's lower-position tie-break is the lower-id one
+        # ids (or another version of the same id, which no query sees with
+        # this one), so top_k's lower-position tie-break is the lower-id one
         _, pos = jax.lax.top_k(cand[0], kc)
         return tuple(jnp.take_along_axis(x, pos, 1) for x in cand)
 
     return jax.jit(onehot), step
 
 
-def device_chunks(fetch_rows, n_ids: int, chunk: int = 2048) -> list:
-    """Ids ``[0, n_ids)`` uploaded once as ``(rows, ids)`` device chunks of
-    one shape: ``fetch_rows(lo, hi)`` gives the word rows of ids
-    ``[lo, hi)``; the last chunk is padded with empty rows of id -1."""
+#: the version after every other: a row that nothing replaced
+LAST = np.iinfo(np.int32).max
+
+
+def device_chunks(fetch_rows, n_rows: int, chunk: int = 2048, *, ids=None, born=None,
+                  dead=None) -> list:
+    """Rows ``[0, n_rows)`` uploaded once as ``(rows, ids, born, dead)``
+    device chunks of one shape: ``fetch_rows(lo, hi)`` gives the word rows
+    ``[lo, hi)``, ``ids`` their doc ids in ascending order (default: the row
+    numbers), and a row is its doc's contents in the versions ``[born,
+    dead)`` of the corpus (default: all). The last chunk is padded with
+    empty rows of id -1."""
     import jax.numpy as jnp
 
+    ids = np.arange(n_rows) if ids is None else np.asarray(ids)
+    born = np.zeros(n_rows, np.int64) if born is None else np.asarray(born)
+    dead = np.full(n_rows, LAST) if dead is None else np.asarray(dead)
     out = []
-    for lo in range(0, n_ids, chunk):
-        hi = min(lo + chunk, n_ids)
+    for lo in range(0, n_rows, chunk):
+        hi = min(lo + chunk, n_rows)
         rows = fetch_rows(lo, hi)
-        ids = np.arange(lo, lo + chunk, dtype=np.int32)
+        meta = np.zeros((3, chunk), np.int32)
+        meta[0] = -1
+        meta[:, : hi - lo] = ids[lo:hi], born[lo:hi], dead[lo:hi]
         if hi - lo < chunk:
             rows = np.concatenate(
                 [rows, np.full((chunk - (hi - lo), rows.shape[1]), -1, rows.dtype)])
-            ids[hi - lo :] = -1
-        out.append((jnp.asarray(rows), jnp.asarray(ids)))
+        out.append((jnp.asarray(rows), *(jnp.asarray(m) for m in meta)))
     return out
 
 
 def scan_topk(q_idx: np.ndarray, limits: np.ndarray, chunks: list, *, kind: str,
               universe: int, pi: np.ndarray | None = None, n_bins: int = 0, k: int = 10,
-              dtype: str = "float32"):
+              dtype: str = "float32", vers: np.ndarray | None = None):
     """Candidates for the top ``k`` of every query over the stored ``chunks``
     (from ``device_chunks``).
 
     ``q_idx`` (Q, P) are the queries' padded word rows; ``limits`` (Q,)
     admit only ids below it for each query (what had been acknowledged when
-    the query was sent). ``kind`` is ``"binsketch"`` (sets of bins through
-    ``pi``, universe N) or ``"jaccard"`` (sets of words, universe d). Returns
+    the query was sent), and ``vers`` (Q,) (default 0) only the rows that
+    held their doc's contents in that version of the corpus. ``kind`` is
+    ``"binsketch"`` (sets of bins through ``pi``, universe N) or
+    ``"jaccard"`` (sets of words, universe d). Returns
     host arrays (Q, k + MARGIN): device scores (computed in ``dtype``), ids,
     intersection counts, candidate set sizes; and (Q,) query set sizes."""
     import jax.numpy as jnp
@@ -182,14 +199,16 @@ def scan_topk(q_idx: np.ndarray, limits: np.ndarray, chunks: list, *, kind: str,
     pad = -nq % 256  # few query shapes, so few compiles
     q_idx = np.concatenate([q_idx, np.full((pad, q_idx.shape[1]), -1, q_idx.dtype)])
     limits = np.concatenate([limits, np.zeros(pad, limits.dtype)])
+    vers = np.zeros(nq, np.int32) if vers is None else np.asarray(vers)
+    ver = jnp.asarray(np.concatenate([vers, np.zeros(pad, vers.dtype)]).astype(np.int32))
     pi_dev = jnp.asarray(pi if pi is not None else np.zeros(1, np.int32))
     q_hot, q_size = onehot(jnp.asarray(q_idx), pi_dev)
     limit = jnp.asarray(limits.astype(np.int32))
     n = len(q_idx)
     best = (jnp.full((n, kc), -jnp.inf, jnp.float32), jnp.full((n, kc), -1, jnp.int32),
             jnp.zeros((n, kc), jnp.int32), jnp.zeros((n, kc), jnp.int32))
-    for rows, ids in chunks:
-        best = step(q_hot, q_size, limit, rows, ids, pi_dev, best)
+    for rows, ids, born, dead in chunks:
+        best = step(q_hot, q_size, limit, ver, rows, ids, born, dead, pi_dev, best)
     out = [np.asarray(x)[:nq] for x in best]
     return out + [np.asarray(q_size)[:nq].astype(np.int64)]
 

@@ -18,7 +18,8 @@ metric (``bench/metrics/<metric>.py``). A run:
 3. sends the mix's requests in a closed loop for ``--seconds`` (with
    ``--trace 1``: for the mix's ``trace_seconds``, under the profiler);
 4. reads the device's peak memory, frees the engine, and compares the
-   window's answers and the store with the plain reference
+   window's answers and the store with the plain reference, each answer
+   against the corpus as it stood when its query was sent
    (``bench/check.py``);
 5. prints, as the last line of standard output, one JSON object:
    ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's
@@ -80,7 +81,13 @@ def load_spec(root: pathlib.Path, workload: str) -> types.SimpleNamespace:
             corpus.n_words(want):
         raise Refused(2, f"{entry['file']}: n_bins/n_words disagree with Theorem 1 ({want})")
     cfg = dict(cfg, n_bins=want, n_words=corpus.n_words(want))
-    traffic_mod.validate(mix)
+    try:
+        traffic_mod.validate(mix)
+    except ValueError as e:
+        raise Refused(2, f"mix {cell['traffic']!r}: {e}") from e
+    if "update" in traffic_mod.op_weights(mix) and cfg["store"] != "segmented":
+        raise Refused(2, f"mix {cell['traffic']!r} updates docs, which a "
+                         f"{cfg['store']!r} store cannot")
 
     def mine(m):
         return "workloads" not in m or workload in m["workloads"]
@@ -186,8 +193,22 @@ def _send(system, eng, req):
     if req.op == "query":
         s, i = system.query(eng, req.idx, req.k)
         return {"scores": s, "ids": i}
+    if req.op == "update":
+        return {"sealed": system.update(eng, req.keys, req.idx)}
     lo, hi, sealed = system.insert(eng, req.idx)
     return {"lo": lo, "hi": hi, "sealed": sealed}
+
+
+def _acknowledge(gen, hist, seals, req, out) -> None:
+    """A write the system acknowledged: later queries send its contents
+    and the check holds the store to it; ``seals`` gets whether each
+    update sealed the head."""
+    if req.op == "insert":
+        hist.insert(out["lo"], out["hi"], req.pool_lo)
+    elif req.op == "update":
+        gen.ack(req)
+        hist.update(req.keys, req.pool_lo)
+        seals.append(out["sealed"])
 
 
 def run_cell(spec, seed: int, seconds: float, trace: bool, device: dict, peaks: dict, *,
@@ -213,20 +234,17 @@ def run_cell(spec, seed: int, seconds: float, trace: bool, device: dict, peaks: 
     t_built = time.perf_counter()
     n_docs = len(standing)
     gen = traffic_mod.Generator(mix, cfg, seed, standing)
-    acked_hi = n_docs
-    inserts = []  # (lo, hi, pool_lo) of every acknowledged insert
-    for _ in range(mix["warmup_requests"]):
-        req = gen.next()
-        out = _send(system, eng, req)
-        if req.op == "insert":
-            inserts.append((out["lo"], out["hi"], req.pool_lo))
-            acked_hi = max(acked_hi, out["hi"])
+    hist, seals = check.History(standing, gen.pool), []
+    for j in range(mix["warmup_requests"]):
+        req = gen.next(gen.warmup_op(j))
+        _acknowledge(gen, hist, seals, req, _send(system, eng, req))
     n_compiles = clock.compiles
 
     # ---- the window
     trace_dir = str(root / ".bench_cache" / "trace")
     length = min(seconds, mix["trace_seconds"]) if trace else seconds
     records, failed = [], 0
+    phases = getattr(system, "PHASES", {})  # host seconds of each span of a request
     gc_clock = _GcClock()
     t_w0 = time.perf_counter()
     setup_s = t_w0 - t_start
@@ -235,6 +253,8 @@ def run_cell(spec, seed: int, seconds: float, trace: bool, device: dict, peaks: 
             deadline = t_w0 + length
             while time.perf_counter() < deadline:
                 req = gen.next()
+                live, ver = hist.hi, hist.version
+                phases.clear()  # spans of this request's op only
                 before = _host_state(gc_clock)
                 t0 = time.perf_counter()
                 with jax.profiler.TraceAnnotation("bench.request", kind=req.op):
@@ -247,12 +267,11 @@ def run_cell(spec, seed: int, seconds: float, trace: bool, device: dict, peaks: 
                 t1 = time.perf_counter()
                 host = _host_state(gc_clock) - before
                 rec = {"op": req.op, "t0": t0, "t1": t1, "docs": len(req.idx),
-                       "live": acked_hi, "sealed": False, "req": req, "out": out,
-                       "host": host, "phases": dict(getattr(system, "PHASES", {}))}
-                if out is not None and req.op == "insert":
+                       "live": live, "ver": ver, "sealed": False, "req": req, "out": out,
+                       "host": host, "phases": dict(phases)}
+                if out is not None and req.op != "query":
                     rec["sealed"] = out["sealed"]
-                    inserts.append((out["lo"], out["hi"], req.pool_lo))
-                    acked_hi = max(acked_hi, out["hi"])
+                    _acknowledge(gen, hist, seals, req, out)
                 records.append(rec)
     t_w1 = records[-1]["t1"] if records else time.perf_counter()
     in_window = clock.compiles - n_compiles
@@ -278,60 +297,43 @@ def run_cell(spec, seed: int, seconds: float, trace: bool, device: dict, peaks: 
                     f"{1e3 * cpu:.1f} ms, GC {1e3 * gc_s:.1f} ms, page faults {majflt:.0f} "
                     f"major {minflt:.0f} minor, context switches {nvcsw:.0f} voluntary "
                     f"{nivcsw:.0f} involuntary")
+    if hist.updates:
+        docs, rewrites = _rewrites(hist.updates, seals)
+        window = sum(r["op"] == "update" and r["out"] is not None for r in records)
+        log(f"updates: {len(hist.updates)} requests ({window} in the window), {docs} docs, "
+            f"{rewrites} of them rewrites of a doc already in the head; {sum(seals)} seals")
     mem = memory_peak(chips) if device["platform"] == "tpu" else 0
 
     # ---- the program's part of the check, then free it
     ok = [r for r in records if r["out"] is not None]
-    rng = corpus.rng_for(seed, corpus.STREAM_CHECK)
-    pool = gen.pool
-    src = np.full(max(acked_hi - n_docs, 0), -1, np.int64)
-    for lo, hi, plo in inserts:
-        src[lo - n_docs : hi - n_docs] = (plo + np.arange(hi - lo)) % len(pool)
-
-    def content(ids):
-        ids = np.asarray(ids, np.int64)
-        out = np.full((len(ids), cfg["psi"]), -1, np.int32)
-        st = ids < n_docs
-        out[st] = standing[ids[st]]
-        ins = np.nonzero(~st & (ids < acked_hi))[0]
-        if len(ins):
-            rows = src[ids[ins] - n_docs]
-            out[ins[rows >= 0]] = pool[rows[rows >= 0]]
-        return out
-
-    queries = _query_sample(ok, mix, rng, cfg["psi"])
+    queries, sample, f_ids = _check_draws(seed, mix, ok, hist, cfg["psi"])
     numbers = {"failed_requests": failed}
     find = None
-    if inserts:
-        expected = np.concatenate([np.arange(n_docs)] +
-                                  [np.arange(lo, hi) for lo, hi, _ in inserts])
-        ins_ids = expected[n_docs:]
-        sample = np.union1d(rng.choice(ins_ids, min(mix["check_sample"], len(ins_ids)),
-                                       replace=False),
-                            np.arange(inserts[-1][0], inserts[-1][1]))
-        numbers.update(check.store_numbers(system.views(eng), expected, sample, content,
-                                           pi=pi, n_bins=cfg["n_bins"]))
-        nf = min(mix.get("findability_queries", 0), len(ins_ids))
-        if nf:
-            f_ids = rng.choice(ins_ids, nf, replace=False)
-            f_idx = content(f_ids)
+    if hist.inserts or hist.updates:
+        numbers.update(check.store_numbers(
+            system.views(eng), np.concatenate([np.arange(n_docs), hist.inserted_ids()]),
+            sample, hist.newest, pi=pi, n_bins=cfg["n_bins"]))
+        if len(f_ids):
+            f_idx = hist.newest(f_ids)
             s, i = system.query(eng, f_idx, 10)
-            find = (f_idx, np.full(nf, acked_hi), s, i)
+            find = (f_idx, np.full(len(f_ids), hist.hi), np.full(len(f_ids), hist.version),
+                    s, i)
     del eng
     gc.collect()
 
     # ---- the reference
-    q_idx, limits, got_s, got_i, k = queries
+    q_idx, limits, vers, got_s, got_i, k = queries
     if find is not None:
         q_idx = np.concatenate([q_idx, find[0]])
         limits = np.concatenate([limits, find[1]])
-        got_s = np.concatenate([got_s.reshape(-1, 10), find[2]])
-        got_i = np.concatenate([got_i.reshape(-1, 10), find[3]])
+        vers = np.concatenate([vers, find[2]])
+        got_s = np.concatenate([got_s.reshape(-1, 10), find[3]])
+        got_i = np.concatenate([got_i.reshape(-1, 10), find[4]])
         k = 10
     t_ref = time.perf_counter()
-    chunks = reference.device_chunks(lambda lo, hi: content(np.arange(lo, hi)), acked_hi)
+    chunks = hist.chunks()
     if len(q_idx):
-        numbers.update(check.query_numbers(q_idx, limits, got_s, got_i, content, chunks,
+        numbers.update(check.query_numbers(q_idx, limits, vers, got_s, got_i, hist.at, chunks,
                                            pi=pi, n_bins=cfg["n_bins"], k=k))
     w = types.SimpleNamespace(
         records=ok, setup_s=setup_s, window_s=t_w1 - t_w0,
@@ -341,12 +343,13 @@ def run_cell(spec, seed: int, seconds: float, trace: bool, device: dict, peaks: 
     if nq and any(m["name"] == "recall_at_10" for m in spec.e2e) and not trace:
         from bench import truth
 
-        t = truth.device_exact_topk(q_idx[:nq], limits[:nq], chunks, k, vocab=cfg["vocab"])
+        t = truth.device_exact_topk(q_idx[:nq], limits[:nq], chunks, k, vocab=cfg["vocab"],
+                                    vers=vers[:nq])
         w.recall = truth.recall(got_i[:nq], t)
     del chunks
-    log(f"check: {len(q_idx)} answers and {len(inserts)} inserts compared with the "
-        f"reference; the program's part {t_ref - t_w1:.3f} s, the reference's "
-        f"{time.perf_counter() - t_ref:.3f} s")
+    log(f"check: {len(q_idx)} answers, {len(hist.inserts)} inserts and {len(hist.updates)} "
+        f"updates compared with the reference; the program's part {t_ref - t_w1:.3f} s, "
+        f"the reference's {time.perf_counter() - t_ref:.3f} s")
 
     # ---- metrics
     dev = dict(device, memory_peak_bytes=mem)
@@ -409,23 +412,64 @@ def _host_state(gc_clock) -> np.ndarray:
                      ru.ru_nvcsw, ru.ru_nivcsw], np.float64)
 
 
+def _check_draws(seed, mix, records, hist, psi):
+    """What the check compares, drawn from the seed: the window's queries
+    (``_query_sample``), the written docs whose stored sketches are compared,
+    and the written docs sent as queries after the window. Inserted docs
+    are drawn from the check's stream, after the queries; updated docs from
+    a stream of their own."""
+    rng = corpus.rng_for(seed, corpus.STREAM_CHECK)
+    rng_u = corpus.rng_for(seed, corpus.STREAM_UPDATE_CHECK)
+    queries = _query_sample(records, mix, rng, psi)
+    ins_ids, upd_ids = hist.inserted_ids(), hist.updated_ids()
+    sample = np.zeros(0, np.int64)
+    if len(ins_ids):
+        sample = _drawn(rng, ins_ids, mix["check_sample"], np.arange(*hist.inserts[-1][:2]))
+    if len(upd_ids):
+        sample = np.union1d(sample, _drawn(rng_u, upd_ids, mix["check_sample"],
+                                           hist.updates[-1][0]))
+    nf = mix.get("findability_queries", 0)
+    f_ids = np.concatenate([np.zeros(0, np.int64)] + [
+        _drawn(r, ids, nf) for r, ids in ((rng, ins_ids), (rng_u, upd_ids)) if len(ids)])
+    return queries, sample, f_ids
+
+
 def _query_sample(records, mix, rng, psi):
-    """(rows, limits, scores, ids, k) of at most ``check_queries`` window
-    queries, drawn from the seed."""
+    """(rows, limits, versions, scores, ids, k) of at most ``check_queries``
+    window queries, drawn from the seed."""
     qs = [r for r in records if r["op"] == "query"]
     if not qs:
-        return (np.zeros((0, psi), np.int32), np.zeros(0, np.int64),
+        return (np.zeros((0, psi), np.int32), np.zeros(0, np.int64), np.zeros(0, np.int64),
                 np.zeros((0, 10)), np.zeros((0, 10), np.int64), 10)
     k = qs[0]["req"].k
     rows = np.concatenate([r["req"].idx for r in qs])
     limits = np.concatenate([np.full(r["docs"], r["live"]) for r in qs])
+    vers = np.concatenate([np.full(r["docs"], r["ver"]) for r in qs])
     s = np.concatenate([r["out"]["scores"] for r in qs])
     i = np.concatenate([r["out"]["ids"] for r in qs])
     cap = mix["check_queries"]
     if len(rows) > cap:
         pick = np.sort(rng.choice(len(rows), cap, replace=False))
-        rows, limits, s, i = rows[pick], limits[pick], s[pick], i[pick]
-    return rows, limits, s, i, k
+        rows, limits, vers, s, i = rows[pick], limits[pick], vers[pick], s[pick], i[pick]
+    return rows, limits, vers, s, i, k
+
+
+def _drawn(rng, ids, n, last=()):
+    """``n`` of ``ids`` (at most all) drawn from ``rng``, with every id of
+    ``last``: the docs of the last write request."""
+    got = rng.choice(ids, min(n, len(ids)), replace=False)
+    return np.union1d(got, last) if len(last) else got
+
+
+def _rewrites(updates, seals):
+    """(docs updated, how many of them were in the head already): an update
+    moves each doc it writes into the head, and a seal empties it."""
+    in_head, docs, rewrites = set(), 0, 0
+    for (ids, _), sealed in zip(updates, seals):
+        docs += len(ids)
+        rewrites += len(in_head.intersection(ids.tolist()))
+        in_head = set() if sealed else in_head | set(ids.tolist())
+    return docs, rewrites
 
 
 # ------------------------------------------------------------------ CLI

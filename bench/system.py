@@ -1,9 +1,9 @@
 """The system under test, reached only through its public API.
 
 ``build`` makes a ``SketchEngine`` over the configuration's store from the
-benchmark's corpus and sketch map; ``query`` and ``insert`` are the calls a
-client makes. Each call into the engine sits in a ``bench.*`` profiler
-span, so that the trace can say what the host was doing between device
+benchmark's corpus and sketch map; ``query``, ``insert`` and ``update`` are
+the calls a client makes. Each call into the engine sits in a ``bench.*``
+profiler span, so that the trace can say what the host was doing between device
 operations; ``PHASES`` holds the host seconds of each span of the last
 request, so that a slow request can be told apart without a trace.
 """
@@ -111,9 +111,28 @@ def insert(eng, idx: np.ndarray):
     return ids.start, ids.stop, sealed
 
 
+def update(eng, ids: np.ndarray, idx: np.ndarray) -> bool:
+    """One update request: the contents of docs ``ids`` replaced by the rows
+    ``idx``; returns whether it sealed the head, once the new contents are
+    stored on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    store = eng.store
+    n_sealed = len(store.sealed)
+    with _span("bench.upload"):
+        x = jnp.asarray(idx)
+    with _span("bench.update"):
+        eng.update(ids, x)
+    with _span("bench.settle"):
+        sealed = len(store.sealed) > n_sealed
+        jax.block_until_ready(_written(store, sealed))
+    return sealed
+
+
 def _written(store, sealed: bool):
-    """The device arrays an insert wrote: a segmented store's head (and the
-    segment it sealed), or an append-only store's rows."""
+    """The device arrays an insert or an update wrote: a segmented store's
+    head (and the segment it sealed), or an append-only store's rows."""
     head = getattr(store, "head", None)
     if head is None:
         return [store.sketches]

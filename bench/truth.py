@@ -46,12 +46,13 @@ def exact_topk(corpus_idx, query_idx, k):
     return np.argsort(-sims, axis=1, kind="stable")[:, :k]
 
 
-def device_exact_topk(q_idx, limits, chunks, k, *, vocab: int):
+def device_exact_topk(q_idx, limits, chunks, k, *, vocab: int, vers=None):
     """``exact_topk`` by the reference's device scan over ``chunks``
     (``reference.device_chunks``): (Q, k) ids among ``[0, limits[i])`` for
-    query ``i``."""
+    query ``i``, over the corpus as it stood in version ``vers[i]``."""
     s32, ids, inter, csize, qsize = scan_topk(
-        np.asarray(q_idx), np.asarray(limits), chunks, kind="jaccard", universe=vocab, k=k)
+        np.asarray(q_idx), np.asarray(limits), chunks, kind="jaccard", universe=vocab, k=k,
+        vers=vers)
     exact = np.where(ids >= 0, jaccard32(qsize[:, None], csize, inter), -np.inf)
     return rank(ids, exact, k)[0]
 

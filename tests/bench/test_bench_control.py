@@ -3,7 +3,7 @@ cell can have, planted under the harness at a tiny size."""
 
 import pytest
 
-from bench_testlib import run_tiny
+from bench_testlib import YCSB_CELL, root_for, run_tiny
 from bench import check, control
 
 
@@ -13,9 +13,12 @@ from bench import check, control
     ("nytimes-ro-mlt32", control.AlteredAnswer, "rank_gap"),
     ("nytimes-ro-mlt32", control.HalfBatch, "rank_gap"),
     ("nytimes-seg-ingest", control.UnchangedState, "lost_docs"),
+    (YCSB_CELL, control.UnchangedUpdate, "wrong_sketches"),
+    (YCSB_CELL, control.UntombstonedUpdate, "lost_docs"),
+    (YCSB_CELL, control.StaleReads, "score_gap"),
 ])
-def test_control_and_faults_are_not_correct(workload, system, number):
-    out = run_tiny(workload, seconds=0.3, system=system)
+def test_control_and_faults_are_not_correct(workload, system, number, tmp_path):
+    out = run_tiny(workload, seconds=0.3, system=system, root=root_for(workload, tmp_path))
     assert out["correct"] is False
     assert out["checks"][number]["value"] > check.LIMITS[number], out["checks"]
 

@@ -8,7 +8,7 @@ import sys
 
 import pytest
 
-from bench_testlib import REPO
+from bench_testlib import REPO, YCSB_CELL, checkout_with_ycsb, run_tiny
 from bench import run
 
 
@@ -125,4 +125,39 @@ def test_the_generator_draws_distinct_keys_and_cycles_the_pool():
     with pytest.raises(ValueError):
         traffic.validate(dict(mix, loop="open"))
     with pytest.raises(ValueError):
+        traffic.validate(dict(mix, op="delete"))
+    with pytest.raises(ValueError):  # an update needs a pool of contents
         traffic.validate(dict(mix, op="update"))
+
+
+def test_a_segmented_config_and_a_ycsb_mix_make_a_cell_by_name(tmp_path):
+    """The next cell, YCSB workload B on the segmented store, needs only a
+    configuration file, a mix file and entries: no existing file is edited."""
+    root = checkout_with_ycsb(tmp_path)
+    spec = run.load_spec(root, YCSB_CELL)
+    assert spec.cfg["store"] == "segmented" and spec.cfg["n_bins"] == 34851
+    assert spec.mix["ops"] == {"query": 0.95, "update": 0.05} and spec.mix["keys"] == "zipf"
+    assert [m["name"] for m in spec.e2e] == ["query_qps", "query_p90_ms", "recall_at_10",
+                                             "setup_s"]
+    for name in ("nytimes-ro-mlt32", "nytimes-seg-ingest"):
+        assert run.load_spec(root, name).mix == run.load_spec(REPO, name).mix
+    out = run_tiny(YCSB_CELL, root=root, seconds=0.5)
+    assert out["correct"] is True, out["checks"]
+    assert list(out["metrics"]) == ["query_qps", "query_p90_ms", "recall_at_10", "setup_s"]
+    assert any(line.startswith("updates: ") for line in out["_log"])
+
+
+def test_an_update_mix_on_a_read_only_store_is_refused(tmp_path):
+    root = checkout_with_ycsb(tmp_path)
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["workloads"].append({"name": "ro-ycsb", "config": "nytimes-readonly",
+                               "traffic": "ycsb-b32", "chips": 1, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    with pytest.raises(run.Refused) as e:
+        run.load_spec(root, "ro-ycsb")
+    assert e.value.code == 2 and "updates" in str(e.value)
+    (root / "bench" / "traffic" / "ycsb-b32.json").write_text(json.dumps(
+        {"loop": "closed", "clients": 1, "op": "query", "ops": {"query": 1.0}}))
+    with pytest.raises(run.Refused) as e:  # a malformed mix is refused, not raised
+        run.load_spec(root, YCSB_CELL)
+    assert e.value.code == 2

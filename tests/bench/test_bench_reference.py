@@ -82,3 +82,36 @@ def test_device_truth_equals_the_copied_exact_topk(tiny):
     np.testing.assert_array_equal(got, want)
     assert truth.recall(got, want) == 1.0
     assert truth.recall(np.full_like(got, -1), want) == 0.0
+
+
+def test_versions_admit_the_contents_each_query_saw(tiny):
+    """A scan over every version of every doc (``History.chunks``) finds for
+    each query what a scan over the corpus as it stood then finds."""
+    from bench import check
+
+    cfg, idx, pi, _ = tiny
+    pool = corpus.corpus(dict(cfg, n_docs=64), 4)[0]
+    hist = check.History(idx, pool)
+    rng = np.random.default_rng(1)
+    hot = rng.choice(len(idx), 40, replace=False)
+    for lo in range(0, 64, 16):  # updates of 16 docs, hot docs written again
+        hist.update(rng.choice(hot, 16, replace=False), lo)
+    q = np.concatenate([idx[hot[:8]], pool[:8]])
+    vers = np.arange(len(q)) % (hist.version + 1)
+    chunks = hist.chunks()
+    for kind, universe in (("binsketch", cfg["n_bins"]), ("jaccard", cfg["vocab"])):
+        _, ids, inter, *_ = ref.scan_topk(q, np.full(len(q), len(idx)), chunks, kind=kind,
+                                          universe=universe, pi=pi, n_bins=cfg["n_bins"],
+                                          k=K, vers=vers)
+        for v in range(hist.version + 1):
+            at_v = hist.at(np.arange(len(idx)), np.full(len(idx), v))
+            plain = ref.device_chunks(lambda lo, hi: at_v[lo:hi], len(idx), chunk=200)
+            sel = vers == v
+            _, want_ids, want_inter, *_ = ref.scan_topk(
+                q[sel], np.full(sel.sum(), len(idx)), plain, kind=kind, universe=universe,
+                pi=pi, n_bins=cfg["n_bins"], k=K)
+            np.testing.assert_array_equal(inter[sel][:, :K], want_inter[:, :K])
+            np.testing.assert_array_equal(ids[sel][:, :K], want_ids[:, :K])
+    # an id's newest contents are its last update's
+    last = hist.updates[-1]
+    np.testing.assert_array_equal(hist.newest(last[0]), pool[last[1]])
